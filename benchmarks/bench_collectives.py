@@ -19,17 +19,17 @@ from benchmarks.common import MiB, Row, timeit_us
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.comm import CommSession
-from repro.compat import axis_size, make_mesh, shard_map
 
 #: Payload sizes (MiB) for the hierarchical model rows; --smoke keeps one.
 HIER_SIZES = [8, 64]
 
 
 def _uni_ring_all_gather(x, axis_name):
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     i = jax.lax.axis_index(axis_name)
     cw = [(j, (j + 1) % n) for j in range(n)]
     out = jnp.zeros((n,) + x.shape, x.dtype)
@@ -112,7 +112,8 @@ def run_hierarchical() -> list[Row]:
 
     # Executable two-level decomposition on the (pod, dev) host mesh,
     # validated against the joint psum before timing.
-    mesh = make_mesh((2, 4), ("pod", "dev"))
+    mesh = jax.make_mesh((2, 4), ("pod", "dev"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     x = jnp.asarray(np.random.RandomState(2).randn(16, 256), jnp.float32)
     two = jax.jit(shard_map(
         partial(two_level_all_reduce, inter_axis="pod", intra_axis="dev"),

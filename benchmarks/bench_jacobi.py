@@ -10,7 +10,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.comm import CommSession
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import (Topology, estimate_group_time_s,
                         estimate_transfer_time_s)
 from repro.core.halo import jacobi_step

@@ -19,6 +19,7 @@ JSON artifact (the ``BENCH_*.json`` perf trajectory).
 
 import argparse
 import json
+from pathlib import Path
 
 from benchmarks import common  # noqa: F401 — pins device count first
 
@@ -60,6 +61,8 @@ def main() -> None:
                          "(default: sweep all of "
                          f"{', '.join(common.SCHEDULES)})")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     if args.smoke:
         _apply_smoke()
     if args.schedule:

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.halo import jacobi_step, make_captured_jacobi_step
 
 

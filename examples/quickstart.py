@@ -12,6 +12,7 @@ import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -47,22 +48,28 @@ def main():
     best = sess.tune(0, 1, 64 << 20)
     print(f"tuned: {best.num_paths} paths, {best.num_nodes} nodes")
 
-    # 5) execute for real on the host-device mesh, twice (cache hit)
-    run = CommSession(topology=Topology.full_mesh(8, with_host=False))
+    # 5) execute for real on the devices this process has, twice (cache
+    #    hit): the last device receives from the first
+    n = len(jax.devices())
+    if n < 2:
+        print(f"execution needs >= 2 devices, found {n}; stopping here")
+        return
+    last = n - 1
+    run = CommSession(topology=Topology.full_mesh(n, with_host=False))
     msg = jnp.arange(1 << 20, dtype=jnp.float32)
-    out = run.send(msg, 0, 5)
+    out = run.send(msg, 0, last)
     assert np.array_equal(np.asarray(out), np.asarray(msg))
-    run.send(msg, 0, 5)
+    run.send(msg, 0, last)
 
     # 5b) concurrent messages: one fused transfer group = one compiled
     # launch, planned contention-aware (exchange patterns stay
     # link-disjoint; see DESIGN.md §5)
-    fwd, rev = run.exchange([(msg, 0, 5), (msg * 2, 5, 0)])
+    fwd, rev = run.exchange([(msg, 0, last), (msg * 2, last, 0)])
     assert np.array_equal(np.asarray(rev), np.asarray(msg * 2))
     print(f"fused 2-message exchange OK; dispatches={run.stats()['dispatches']}")
 
     # 6) collectives ride the same session + plan cache
-    x = jnp.asarray(np.random.RandomState(0).randn(8, 16), jnp.float32)
+    x = jnp.asarray(np.random.RandomState(0).randn(n, 16), jnp.float32)
     gathered = run.all_gather(x)
     assert np.allclose(np.asarray(gathered), np.asarray(x))
     print(f"executed transfer + all-gather OK; "

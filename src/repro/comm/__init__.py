@@ -31,7 +31,6 @@ The legacy ``repro.core.paths`` / ``repro.core.multipath`` /
 deprecated shims over this package.
 """
 
-from repro.compat import make_mesh, shard_map  # noqa: F401
 from repro.comm.config import (  # noqa: F401
     COLLECTIVE_STRATEGIES, POLICY_NAMES, SCHEDULE_NAMES, VALIDATE_MODES,
     CommConfig)

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 from repro.core.topology import HOST, Topology
 
 

@@ -62,7 +62,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.comm.cache import (CompiledPlan, FastPathCache, FastPathEntry,
                               TransferPlanCache, compile_plan)
 from repro.comm.capture import CapturedStep, StepCapture, emit_step, lower_step
-from repro.compat import shard_map
 from repro.comm.config import VALIDATE_MODES, _env_bool
 from repro.comm.graph import ComputeNode, TransferGraph, lower
 from repro.comm.health import (LADDER, CommFaultError, FaultInjector,
@@ -336,6 +335,8 @@ class MultiPathTransfer:
         #: ``session.stats()``.
         self.schedule_counts: dict[str, int] = {}
         self._sharding = NamedSharding(mesh, P(None, self.axis_name))
+        #: The staging programs take the message replicated over the mesh.
+        self._replicated = NamedSharding(mesh, P())
         #: Number of compiled-program launches issued (one per transfer or
         #: per fused group — the paper's "one cudaGraphLaunch" count).
         self.dispatches = 0
@@ -444,8 +445,9 @@ class MultiPathTransfer:
             return tuple(emit_graph(graph, xs, ax, itemsizes))
 
         specs = tuple(P(None, ax) for _ in itemsizes)
-        return shard_map(local_body, mesh=self.mesh,
-                         in_specs=specs, out_specs=specs, check_vma=False)
+        return jax.shard_map(local_body, mesh=self.mesh,
+                             in_specs=specs, out_specs=specs,
+                             check_vma=False)
 
     def _compile_group(self, key: GroupKey, graph: TransferGraph,
                        shapes: Sequence[tuple[int, object]]) -> CompiledPlan:
@@ -519,7 +521,8 @@ class MultiPathTransfer:
             # Warm the staging executable once at pool-insertion time so
             # steady-state `staging_ns` measures operand builds, not the
             # one-time jit compile (that is first-dispatch setup cost).
-            jax.block_until_ready(fn(jnp.zeros((nelems,), dtype)))
+            jax.block_until_ready(fn(jax.device_put(
+                jnp.zeros((nelems,), dtype), self._replicated)))
             self._staging[key] = fn
             if len(self._staging) > self._fastpath.capacity:
                 self._staging.popitem(last=False)
@@ -543,7 +546,11 @@ class MultiPathTransfer:
         stagers = [self._stage_fn(window, m.shape[0], m.dtype, p.src)
                    for m, p in zip(messages, entry.plans)]
         t0 = time.perf_counter_ns()
-        xs = [stage(m) for stage, m in zip(stagers, messages)]
+        # A message committed to one device (its source, say) cannot enter
+        # a program over the whole mesh as it is: place it replicated, as
+        # JAX does implicitly for an uncommitted one.
+        xs = [stage(jax.device_put(m, self._replicated))
+              for stage, m in zip(stagers, messages)]
         staging = time.perf_counter_ns() - t0
         self.staging_ns += staging
         compiled = entry.compiled
@@ -702,8 +709,8 @@ class MultiPathTransfer:
         in_specs = tuple(P() if buffers[b].replicated else P(ax)
                          for b in input_ids)
         out_specs = tuple(P(ax) for _ in outputs)
-        return shard_map(local_body, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(local_body, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _step_abstracts(self, program: StepCapture) -> tuple:
         abstracts = []
@@ -900,26 +907,29 @@ class MultiPathTransfer:
             try:
                 entry = self.resolve_step(step, schedule)
                 self._fault_check(entry)
-                out = self._launch_step(entry, arrays, block=block)
-                level = self._steady_rung(0)
-                if hs.ladder_level != level:
-                    hs.note("ladder", level=level, rung=LADDER[level],
-                            dispatch=self.dispatches)
-                hs.ladder_level = level
-                if self.monitor is not None:
-                    self.monitor.maybe_probe(self)
-                return out
             except LinkFaultError as exc:
                 history.append(f"step: {exc}")
                 self._note_fault(exc, 1)
                 if delay > 0:
                     time.sleep(delay)
                     delay = min(delay * 2, 0.05)
+                continue
             except ValueError as exc:
                 history.append(f"step: {exc}")
                 raise CommFaultError(
                     f"captured-step ladder exhausted: {exc}",
                     history) from exc
+            # Outside the handlers: an error of the launch itself (XLA or
+            # Mosaic, placement, runtime) says nothing about routes.
+            out = self._launch_step(entry, arrays, block=block)
+            level = self._steady_rung(0)
+            if hs.ladder_level != level:
+                hs.note("ladder", level=level, rung=LADDER[level],
+                        dispatch=self.dispatches)
+            hs.ladder_level = level
+            if self.monitor is not None:
+                self.monitor.maybe_probe(self)
+            return out
         raise CommFaultError(
             "captured-step dispatch failed after retries", history)
 
@@ -1008,10 +1018,12 @@ class MultiPathTransfer:
                     f"degradation ladder exhausted for {src}->{dst}: no "
                     f"surviving device route and no host-staged route",
                     history)
+        devices = self.mesh.devices.flat
         outs = []
-        for (_, _, _, dtype), m in zip(specs, messages):
-            staged = jax.device_get(m)           # PCIe pull to host
-            outs.append(jnp.asarray(staged, dtype))  # PCIe push to dst
+        for (_, dst, _, dtype), m in zip(specs, messages):
+            staged = jax.device_get(m)                      # pull to host
+            outs.append(jax.device_put(staged.astype(dtype, copy=False),
+                                       devices[dst]))       # push to dst
         hs = self.health
         hs.host_relays += 1
         hs.ladder_level = 3
@@ -1090,16 +1102,6 @@ class MultiPathTransfer:
                         exclusive=exclusive and rung == 0,
                         schedule=schedule, single=single)
                     self._fault_check(entry)
-                    out = self._launch(entry, messages, block=block)
-                    level = self._steady_rung(rung)
-                    if hs.ladder_level != level:
-                        hs.note("ladder", level=level,
-                                rung=LADDER[level],
-                                dispatch=self.dispatches)
-                    hs.ladder_level = level
-                    if self.monitor is not None:
-                        self.monitor.maybe_probe(self)
-                    return out
                 except LinkFaultError as exc:
                     failed_once = True
                     history.append(f"{LADDER[rung]}: {exc}")
@@ -1108,10 +1110,23 @@ class MultiPathTransfer:
                     if delay > 0:
                         time.sleep(delay)
                         delay = min(delay * 2, 0.05)
+                    continue
                 except ValueError as exc:
                     failed_once = True
                     history.append(f"{LADDER[rung]}: {exc}")
                     break  # no admissible route at this rung: escalate
+                # Outside the handlers: an error of the launch itself (XLA
+                # or Mosaic, placement, runtime) says nothing about routes
+                # and must reach the caller, not the host relay.
+                out = self._launch(entry, messages, block=block)
+                level = self._steady_rung(rung)
+                if hs.ladder_level != level:
+                    hs.note("ladder", level=level, rung=LADDER[level],
+                            dispatch=self.dispatches)
+                hs.ladder_level = level
+                if self.monitor is not None:
+                    self.monitor.maybe_probe(self)
+                return out
         return self._host_relay(specs, messages, history)
 
     # -- public API ---------------------------------------------------------

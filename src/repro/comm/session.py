@@ -47,11 +47,9 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm import collectives as coll
-from repro import compat
 from repro.comm.cache import CompiledPlan, TransferPlanCache, compile_plan
 from repro.comm.calibration import (CalibrationFitter, CalibrationProfile,
                                     modeled_vs_measured)
-from repro.compat import shard_map
 from repro.comm.config import CommConfig
 from repro.comm.engine import MultiPathTransfer
 from repro.comm.graph import canonical_digest, lower
@@ -115,7 +113,7 @@ class BoundCollectives:
         return coll.psum_via_multipath(x, self.axis_name)
 
     def pmean(self, x: jax.Array) -> jax.Array:
-        return self.psum(x) / compat.axis_size(self.axis_name)
+        return self.psum(x) / jax.lax.axis_size(self.axis_name)
 
 
 class CommSession:
@@ -372,8 +370,8 @@ class CommSession:
         in_sharding = NamedSharding(self.mesh, in_spec)
 
         def build() -> CompiledPlan:
-            fn = shard_map(local_fn, mesh=self.mesh, in_specs=in_spec,
-                           out_specs=out_spec, check_vma=False)
+            fn = jax.shard_map(local_fn, mesh=self.mesh, in_specs=in_spec,
+                               out_specs=out_spec, check_vma=False)
             abstract = jax.ShapeDtypeStruct(x.shape, x.dtype,
                                             sharding=in_sharding)
             return compile_plan(key, fn, (abstract,), num_nodes=num_nodes)

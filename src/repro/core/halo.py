@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.comm.session import CommSession

@@ -26,7 +26,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -131,7 +130,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
             pltpu.VMEM((block_q, d), jnp.float32),     # accumulator
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,8 +1,9 @@
 """Jit'd wrapper for the flash attention kernel.
 
-On CPU the kernel runs in interpret mode; ``flash_attention`` transparently
-falls back to the reference for head dims the kernel does not tile well
-(d not a multiple of 8) so model code can call it unconditionally.
+On the CPU backend the kernel runs in Pallas interpret mode; elsewhere it
+runs compiled. There is no fallback to the reference: shapes the kernel
+cannot tile (on TPU the last two block dims must be divisible by 8 and
+128, so a single-query ``(B, H, 1, D)`` q is refused) raise.
 """
 
 from __future__ import annotations
@@ -12,7 +13,6 @@ import functools
 import jax
 
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
-from repro.kernels.flash_attention.ref import attention_ref
 
 
 def _is_cpu() -> bool:

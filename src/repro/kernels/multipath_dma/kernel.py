@@ -24,6 +24,12 @@ analogue and is rejected; staging buffers live in the via-chip's VMEM,
 sized per-chunk — hop-granular flow control comes from the per-chunk
 staging slots (a production kernel would credit-signal to reuse two slots;
 we allocate ``num_chunks`` slots which bounds VMEM by the path share).
+
+Alignment: Mosaic tiles a 1-D HBM or VMEM buffer in runs of
+:data:`DMA_TILE_ELEMS` elements and refuses a DMA slice that does not start
+and end on that tiling. Plans for this kernel are therefore made at
+:func:`dma_granularity` bytes, and each staging buffer is one flat run of
+equal slots so that every slot starts on a tile.
 """
 
 from __future__ import annotations
@@ -36,23 +42,35 @@ from jax import lax
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params, pallas_interpret_flag
-
 from repro.comm.plan import TransferPlan
 from repro.core.topology import HOST
 
 
+#: Elements per tile of a 1-D buffer under Mosaic, for every dtype the
+#: kernel moves (f32, bf16 and int8 alike, checked by compiling for v5e).
+DMA_TILE_ELEMS = 1024
+
+
+def dma_granularity(dtype) -> int:
+    """Chunk granularity in bytes for plans this kernel executes: pass it
+    as ``granularity=`` to the planner."""
+    return DMA_TILE_ELEMS * jnp.dtype(dtype).itemsize
+
+
 def _element_bounds(plan: TransferPlan, itemsize: int):
     """Static (path -> [(offset_elems, size_elems)]) chunk table."""
+    gran = DMA_TILE_ELEMS * itemsize
     table = []
     for pa in plan.paths:
         if pa.route.via == HOST:
             raise ValueError("host-staged path not executable on TPU mesh")
         chunks = []
         for off_b, size_b in pa.chunk_bounds():
-            if off_b % itemsize or size_b % itemsize:
-                raise ValueError("plan not element-aligned; use "
-                                 "granularity=itemsize")
+            if off_b % gran or size_b % gran:
+                raise ValueError(
+                    f"chunk ({off_b}, {size_b}) is not aligned to the DMA "
+                    f"tiling of {DMA_TILE_ELEMS} elements; plan with "
+                    f"granularity=dma_granularity(dtype)")
             chunks.append((off_b // itemsize, size_b // itemsize))
         table.append(chunks)
     return table
@@ -114,15 +132,16 @@ def _multipath_dma_kernel(x_ref, o_ref, *scratch, plan: TransferPlan,
         else:
             # ---- staged path: hop-1 into via's staging slot, hop-2 out ----
             stage = stage_refs[p]
+            slot = max(size for _, size in chunks)   # 1-D slot stride
             for c, (off, size) in enumerate(chunks):
                 h1 = pltpu.make_async_remote_copy(
                     src_ref=x_ref.at[pl.ds(off, size)],
-                    dst_ref=stage.at[c, pl.ds(0, size)],
+                    dst_ref=stage.at[pl.ds(c * slot, size)],
                     send_sem=h1_send.at[p, c], recv_sem=h1_recv.at[p, c],
                     device_id=(via,),
                     device_id_type=pltpu.DeviceIdType.MESH)
                 h2 = pltpu.make_async_remote_copy(
-                    src_ref=stage.at[c, pl.ds(0, size)],
+                    src_ref=stage.at[pl.ds(c * slot, size)],
                     dst_ref=o_ref.at[pl.ds(off, size)],
                     send_sem=h2_send.at[p, c], recv_sem=h2_recv.at[p, c],
                     device_id=(dst,),
@@ -142,20 +161,20 @@ def _multipath_dma_kernel(x_ref, o_ref, *scratch, plan: TransferPlan,
                     h2.wait_recv()
 
             @pl.when(my == src)
-            def _(p=p, chunks=chunks, via=via, stage=stage):
+            def _(p=p, chunks=chunks, via=via, stage=stage, slot=slot):
                 for c, (off, size) in enumerate(chunks):
                     pltpu.make_async_remote_copy(
                         src_ref=x_ref.at[pl.ds(off, size)],
-                        dst_ref=stage.at[c, pl.ds(0, size)],
+                        dst_ref=stage.at[pl.ds(c * slot, size)],
                         send_sem=h1_send.at[p, c], recv_sem=h1_recv.at[p, c],
                         device_id=(via,),
                         device_id_type=pltpu.DeviceIdType.MESH).wait_send()
 
             @pl.when(my == via)
-            def _(p=p, chunks=chunks, stage=stage):
+            def _(p=p, chunks=chunks, stage=stage, slot=slot):
                 for c, (off, size) in enumerate(chunks):
                     pltpu.make_async_remote_copy(
-                        src_ref=stage.at[c, pl.ds(0, size)],
+                        src_ref=stage.at[pl.ds(c * slot, size)],
                         dst_ref=o_ref.at[pl.ds(off, size)],
                         send_sem=h2_send.at[p, c], recv_sem=h2_recv.at[p, c],
                         device_id=(dst,),
@@ -185,7 +204,7 @@ def build_multipath_dma(plan: TransferPlan, nelems: int, dtype,
         # minimal placeholder so scratch indices stay aligned with paths.
         slots = len(chunks) if pa.route.via is not None else 1
         size = max_size if pa.route.via is not None else 8
-        scratch.append(pltpu.VMEM((slots, size), dtype))
+        scratch.append(pltpu.VMEM((slots * size,), dtype))
     scratch += [
         pltpu.SemaphoreType.DMA,                        # init
         pltpu.SemaphoreType.DMA((npaths, max_chunks)),  # h1 send
@@ -204,6 +223,6 @@ def build_multipath_dma(plan: TransferPlan, nelems: int, dtype,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=scratch,
-        compiler_params=pallas_tpu_compiler_params(collective_id=collective_id),
-        interpret=pallas_interpret_flag(interpret),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.comm.plan import TransferPlan
 from repro.kernels.multipath_dma.kernel import build_multipath_dma

@@ -26,7 +26,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params, pallas_interpret_flag
 
 
 def _ring_ag_kernel(x_ref, o_ref, init_sem, cw_send, cw_recv, ccw_send,
@@ -91,8 +90,8 @@ def build_ring_allgather(shard_shape: tuple, dtype, num_devices: int, *,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA]
         + [pltpu.SemaphoreType.DMA((max(1, num_devices - 1),))] * 4,
-        compiler_params=pallas_tpu_compiler_params(collective_id=collective_id),
-        interpret=pallas_interpret_flag(interpret),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )
 
     def fn(x_local):

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.kernels.ring_allgather.kernel import build_ring_allgather
 

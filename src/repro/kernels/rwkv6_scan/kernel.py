@@ -35,7 +35,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
@@ -104,7 +103,7 @@ def rwkv6_scan_kernel(r: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=seq_spec_v,
         out_shape=jax.ShapeDtypeStruct((bh, s, dv), r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

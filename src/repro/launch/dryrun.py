@@ -38,7 +38,7 @@ import json
 import time
 import traceback
 
-from repro.compat import set_mesh
+from jax import set_mesh
 
 
 def _cost_tuple(compiled, default_group):

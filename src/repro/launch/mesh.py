@@ -17,8 +17,8 @@ agree on which machine a config runs on.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
 from repro.configs.base import ArchConfig
 from repro.core.topology import Topology
 
@@ -31,7 +31,7 @@ DCN_LINK_GBPS = 25.0
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def production_mesh_shape(*, multi_pod: bool = False
@@ -78,4 +78,4 @@ def make_host_mesh(shape=None, axes=("data", "model")) -> jax.sharding.Mesh:
     n = len(jax.devices())
     if shape is None:
         shape = (1, n)
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

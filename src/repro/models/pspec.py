@@ -18,7 +18,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh
+from jax.sharding import get_abstract_mesh
 
 DP = ("pod", "data")   # logical batch axes (filtered per ambient mesh)
 

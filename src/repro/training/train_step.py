@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import ArchConfig
 from repro.models import transformer as tfm
 from repro.optim import OptimConfig, apply_updates, init_opt_state

@@ -11,10 +11,10 @@ os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402
+from jax.extend.core import ClosedJaxpr, Jaxpr  # noqa: E402
 
 import pytest  # noqa: E402
 
-from repro.compat import make_mesh  # noqa: E402
 from repro.core import HOST, Link, Topology  # noqa: E402
 
 
@@ -27,7 +27,37 @@ def dev_mesh():
 @pytest.fixture(scope="session")
 def dp_tp_mesh():
     """2-D (data=2, model=4) mesh used by model-sharding tests."""
-    return make_mesh((2, 4), ("data", "model"))
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+
+def _count_matching(jaxpr, match) -> int:
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += bool(match(eqn))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    total += _count_matching(sub, match)
+    return total
+
+
+@pytest.fixture
+def count_eqns():
+    """``count_eqns(fn, abstract_args, match)``: how many equations of
+    ``jax.make_jaxpr(fn)(*abstract_args)``, sub-jaxprs included, match
+    ``match`` — a primitive name (e.g. ``"ppermute"``) or a predicate on
+    the equation. The traced-count half of the DESIGN §2 equal-graph
+    invariant."""
+    def count(fn, abstract_args, match):
+        if isinstance(match, str):
+            name = match
+            match = lambda eqn: eqn.primitive.name == name  # noqa: E731
+        return _count_matching(jax.make_jaxpr(fn)(*abstract_args).jaxpr,
+                               match)
+    return count
 
 
 # -- shared topology fixture library ----------------------------------------

@@ -20,29 +20,13 @@ from repro.comm import (CommConfig, CommSession, ComputeNode, StepCapture,
 from repro.comm.calibration import CalibrationFitter
 from repro.comm.capture import BufferSpec, lower_step
 from repro.comm.telemetry import DispatchSample, StageTimings
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.halo import jacobi_step, make_captured_jacobi_step
 
 
 @pytest.fixture()
 def sess(dev_mesh):
     return CommSession(mesh=dev_mesh)
-
-
-def _count_eqns(fn, abstract_args, match):
-    def count(jaxpr):
-        total = 0
-        for eqn in jaxpr.eqns:
-            if match(eqn):
-                total += 1
-            for v in eqn.params.values():
-                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        total += count(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        total += count(sub)
-        return total
-    return count(jax.make_jaxpr(fn)(*abstract_args).jaxpr)
 
 
 # ------------------------- Jacobi acceptance --------------------------------
@@ -70,7 +54,7 @@ def test_captured_jacobi_one_dispatch_bitwise_eager(sess):
     assert sess.stats()["fastpath"]["hits"] >= 1
 
 
-def test_captured_jacobi_traced_launch_counts(sess):
+def test_captured_jacobi_traced_launch_counts(sess, count_eqns):
     """Traced ppermute + kernel-call count == scheduled num_nodes: the
     compiled step program contains exactly the graph's copy nodes as
     ppermutes and its compute nodes as ``capk_*`` jit calls."""
@@ -80,9 +64,8 @@ def test_captured_jacobi_traced_launch_counts(sess):
     graph = entry.graph
     fn = eng._build_step_fn(entry.program, graph, entry.outputs)
     abstracts = eng._step_abstracts(entry.program)
-    ppermutes = _count_eqns(
-        fn, abstracts, lambda e: e.primitive.name == "ppermute")
-    kernels = _count_eqns(
+    ppermutes = count_eqns(fn, abstracts, "ppermute")
+    kernels = count_eqns(
         fn, abstracts,
         lambda e: str(e.params.get("name", "")).startswith("capk_"))
     assert ppermutes == graph.num_copy_nodes
@@ -339,11 +322,12 @@ def test_captured_multipath_dma_lowers_into_mixed_graph(sess):
 
     rec = TimelineRecorder(enabled=True)
     rec.record_kernel("multipath_dma", 25_000.0)
+    from repro.kernels.multipath_dma.kernel import dma_granularity
     n = sess.engine.num_devices
-    nelems = 256
+    nelems = 2048
     planner = PathPlanner(sess.topology, multipath_threshold=64)
     plan = planner.plan(0, 2, nelems * 4, max_paths=2, num_chunks=2,
-                        granularity=4)
+                        granularity=dma_granularity(jnp.float32))
 
     def plan_group_fn(specs, *, max_paths=None, num_chunks=None):
         from repro.comm import TransferRequest

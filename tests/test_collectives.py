@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core.collectives import (bidir_ring_all_gather,
                                     bidir_ring_reduce_scatter,

@@ -270,7 +270,7 @@ def test_session_cache_accounting_across_ops(session):
 def test_session_collectives_match_references(session):
     import jax
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     x = jnp.asarray(np.random.RandomState(1).randn(16, 6), jnp.float32)
     got = session.all_gather(x)
@@ -293,7 +293,7 @@ def test_session_collectives_match_references(session):
 def test_session_all_to_all_roundtrip(session):
     import jax
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     n = 8
     x = jnp.asarray(np.random.RandomState(2).randn(n * n, 4), jnp.float32)

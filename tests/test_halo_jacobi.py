@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core.halo import halo_exchange_ring, jacobi_step
 from repro.kernels.jacobi import ref as j_ref

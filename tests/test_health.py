@@ -351,6 +351,39 @@ def test_ladder_host_relay_when_no_device_route(mesh4):
         sess2.send(x, 0, 1)                    # ladder truly exhausted
 
 
+def test_host_relay_lands_on_destination_device(mesh4):
+    """The staged-host rung delivers onto ``dst``'s device, not onto the
+    default device."""
+    topo = Topology.full_mesh(4)
+    sess = _session(topo, mesh4)
+    x = jnp.arange(1024, dtype=jnp.float32)
+    for a in (0, 1, 2):
+        topo.fail_link(a, 3)                   # no device route into 3
+    out = sess.send(x, 0, 3)
+    assert sess.stats()["health"]["host_relays"] == 1
+    assert out.devices() == {mesh4.devices.flat[3]}
+    np.testing.assert_array_equal(out, x)
+
+
+def test_launch_error_under_fault_state_is_not_relayed(mesh4,
+                                                       monkeypatch):
+    """An error from the launch itself (an XLA, Mosaic or placement
+    error on the chip) reaches the caller under fault state instead of
+    escalating the ladder to the host relay."""
+    topo = Topology.full_mesh(4)
+    sess = _session(topo, mesh4)
+    topo.fail_link(0, 1)                       # hazard: degraded loop
+    x = jnp.arange(1024, dtype=jnp.float32)
+
+    def refuse(*args, **kwargs):
+        raise ValueError("refused by the device compiler")
+
+    monkeypatch.setattr(sess.engine, "_launch", refuse)
+    with pytest.raises(ValueError, match="device compiler"):
+        sess.send(x, 0, 2)
+    assert sess.stats()["health"]["host_relays"] == 0
+
+
 def test_healthy_path_unchanged_and_exclusive_contract():
     """With health on but no fault state, dispatch takes the pristine
     path: exclusive=True starvation still raises ValueError (the ladder

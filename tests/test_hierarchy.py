@@ -24,7 +24,6 @@ from repro.comm import (CommConfig, CommSession, FastPathCache,
                         two_level_all_reduce)
 from repro.comm.cache import FastPathEntry
 from repro.comm.config import COLLECTIVE_STRATEGIES
-from repro.compat import make_mesh, shard_map
 from repro.core import HOST, Link, Topology, validate_plan
 
 MiB = 1 << 20
@@ -195,12 +194,13 @@ def test_select_strategy_auto_and_forced(two_island, mesh4):
 
 
 def test_two_level_all_reduce_matches_joint_psum():
-    mesh = make_mesh((2, 4), ("pod", "dev"))
+    mesh = jax.make_mesh((2, 4), ("pod", "dev"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     x = jnp.asarray(np.random.RandomState(0).randn(16, 64), jnp.float32)
-    two = jax.jit(shard_map(
+    two = jax.jit(jax.shard_map(
         partial(two_level_all_reduce, inter_axis="pod", intra_axis="dev"),
         mesh=mesh, in_specs=P("dev"), out_specs=P("dev"), check_vma=False))
-    ref = jax.jit(shard_map(
+    ref = jax.jit(jax.shard_map(
         lambda v: jax.lax.psum(v, ("pod", "dev")),
         mesh=mesh, in_specs=P("dev"), out_specs=P("dev"), check_vma=False))
     np.testing.assert_allclose(np.asarray(two(x)), np.asarray(ref(x)),

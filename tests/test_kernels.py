@@ -8,15 +8,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import has_pallas_tpu_interpret_mode
 from repro.core import PathPlanner, Topology
 
-requires_remote_dma_interpret = pytest.mark.skipif(
-    not has_pallas_tpu_interpret_mode(),
-    reason="remote-DMA kernels need jax's typed TPU interpret mode "
-           "(pltpu.InterpretParams); this jax only has plain interpret=True")
-
 # ------------------------------ multipath DMA ------------------------------
+from repro.kernels.multipath_dma import kernel as dma_kernel
 from repro.kernels.multipath_dma import ops as dma_ops
 from repro.kernels.multipath_dma import ref as dma_ref
 
@@ -28,21 +23,31 @@ def mesh4():
 
 
 @pytest.mark.parametrize("nelems,paths,chunks", [
-    (512, 1, 1), (512, 2, 2), (1024, 3, 4), (768, 3, 3), (2048, 2, 8),
+    (1024, 1, 1), (2048, 2, 2), (12288, 3, 4), (9216, 3, 3), (16384, 2, 8),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@requires_remote_dma_interpret
 def test_dma_kernel_sweep(mesh4, nelems, paths, chunks, dtype):
     topo = Topology.full_mesh(4)
     planner = PathPlanner(topo, multipath_threshold=4)
     itemsize = jnp.dtype(dtype).itemsize
-    plan = planner.plan(0, 1, nelems * itemsize, granularity=itemsize,
+    plan = planner.plan(0, 1, nelems * itemsize,
+                        granularity=dma_kernel.dma_granularity(dtype),
                         max_paths=paths, num_chunks=chunks)
     x = np.random.RandomState(0).randn(4, nelems).astype(dtype)
     got = np.asarray(dma_ops.multipath_dma_transfer(jnp.asarray(x), plan,
                                                     mesh4))
     ref = dma_ref.multipath_transfer_ref(np.asarray(x, np.float64), plan)
     np.testing.assert_array_equal(got.astype(np.float64), ref)
+
+
+def test_dma_kernel_rejects_unaligned_chunks():
+    """A chunk that does not fall on the 1024-element DMA tiling is
+    refused before Mosaic sees it (Mosaic refuses such a slice on v5e)."""
+    planner = PathPlanner(Topology.full_mesh(4), multipath_threshold=4)
+    plan = planner.plan(0, 3, 768 * 4, granularity=4, max_paths=3,
+                        num_chunks=3)
+    with pytest.raises(ValueError, match="DMA tiling"):
+        dma_kernel.build_multipath_dma(plan, 768, jnp.float32, 4)
 
 
 def test_dma_kernel_rejects_3hop(mesh4):
@@ -139,7 +144,6 @@ from repro.kernels.ring_allgather import ops as ag_ops
 @pytest.mark.parametrize("n", [4, 8])
 @pytest.mark.parametrize("rows,f", [(8, 128), (4, 64), (8, 7)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@requires_remote_dma_interpret
 def test_ring_allgather_sweep(n, rows, f, dtype):
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:n]), ("dev",))
     x = jnp.asarray(np.random.RandomState(0).randn(n * rows, f), dtype)

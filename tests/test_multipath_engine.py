@@ -24,6 +24,19 @@ def test_transfer_roundtrip(engine, nelems, dtype):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(msg))
 
 
+def test_transfer_of_messages_committed_to_their_source(engine):
+    """Messages placed on their source devices (as on a multi-chip host)
+    are accepted, alone and in a group, and arrive intact."""
+    devs = engine.mesh.devices.flat
+    a = jax.device_put(jnp.arange(4096, dtype=jnp.float32), devs[2])
+    b = jax.device_put(jnp.arange(4096, dtype=jnp.float32) * 2.0, devs[5])
+    np.testing.assert_array_equal(np.asarray(engine.transfer(a, 2, 5)),
+                                  np.asarray(a))
+    fwd, rev = engine.transfer_group([a, b], [(2, 5), (5, 2)])
+    np.testing.assert_array_equal(np.asarray(fwd), np.asarray(a))
+    np.testing.assert_array_equal(np.asarray(rev), np.asarray(b))
+
+
 def test_bidirectional_group(engine):
     """Opposite-direction traffic is a 2-transfer group (the old
     ``bidirectional=True`` flag); BOTH receptions are returned."""

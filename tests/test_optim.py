@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.optim import (OptimConfig, apply_updates, compressed_psum,
                          compressed_psum_with_feedback, global_norm,

@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh, set_mesh
+from jax import set_mesh
+from jax.sharding import AbstractMesh
 
 from repro.configs import REGISTRY, load_all
 from repro.data import DataConfig, SyntheticDataset
@@ -18,8 +19,8 @@ from repro.training import sharding as shd
 load_all()
 ALL = sorted(REGISTRY)
 
-SINGLE = abstract_mesh((16, 16), ("data", "model"))
-MULTI = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_specs(specs, shapes, mesh):

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import set_mesh
+from jax import set_mesh
 
 from repro.configs import REGISTRY, load_all
 from repro.data import DataConfig, SyntheticDataset

@@ -1,0 +1,127 @@
+"""Compiles for a described TPU v5e, at the sizes the chip smoke runs.
+
+Nothing here runs on a chip: the TPU compiler, which is installed with JAX,
+compiles each program for a v5e:2x2 topology that is described, not
+attached. A program or kernel the chip's compiler would refuse (a slice off
+the Mosaic tiling, a scoped-VMEM overrun, a program larger than HBM) fails
+here. The topology is described only inside the ``topo`` fixture: a test
+worker that never runs this file never loads the TPU library.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.comm import MultiPathTransfer
+from repro.core import PathPlanner, Topology
+
+MiB = 1 << 20
+GiB = 1 << 30
+#: HBM of one TPU v5e chip.
+V5E_HBM_BYTES = 16 * GiB
+#: The chip smoke's 512 MiB f32 messages and its one-chip Jacobi grid.
+MSG_ELEMS = 512 * MiB // 4
+JACOBI_ROWS, JACOBI_COLS = 8, 1 << 25
+#: A diagonal message whose remote-DMA staging fits the 16 MiB of scoped
+#: VMEM a kernel gets by default (24 MiB still compiles, 32 MiB does not).
+DMA_BYTES = 16 * MiB
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no TPU here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep the cache out of it.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices), ("dev",))
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _fits_one_chip(compiled) -> None:
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("pairs", [((0, 3),), ((0, 3), (3, 0))],
+                         ids=["send_0_3", "exchange_0_3"])
+def test_engine_group_program_512mib(mesh4, pairs):
+    """The engine's program for 512 MiB f32 messages on a v5e:2x2 mesh:
+    multipath plans, one ppermute per copy node, within one chip's HBM."""
+    eng = MultiPathTransfer(mesh4,
+                            topology=Topology.full_mesh(4, with_host=False))
+    specs = [(s, d, MSG_ELEMS, jnp.float32) for s, d in pairs]
+    plan_cp, group = eng.compiled_for_group(specs)
+    assert all(p.num_paths > 1 for p in group.plans)
+    text = plan_cp.compiled.as_text()
+    assert text.count("collective-permute-start") >= sum(
+        p.num_nodes for p in group.plans)
+    _fits_one_chip(plan_cp.compiled)
+
+
+def test_jacobi_kernel_at_smoke_size(one_chip):
+    from repro.kernels.jacobi.kernel import jacobi_sweep_kernel
+    ext = jax.ShapeDtypeStruct((JACOBI_ROWS, JACOBI_COLS + 2), jnp.float32,
+                               sharding=one_chip)
+    fn = jax.jit(functools.partial(jacobi_sweep_kernel, interpret=False))
+    compiled = fn.lower(ext).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+def test_multipath_dma_kernel_compiles(mesh4):
+    """The remote-DMA kernel, with chunks planned on its DMA tiling,
+    compiles for the chip (not interpreted) on the diagonal pair."""
+    from repro.kernels.multipath_dma.kernel import (build_multipath_dma,
+                                                    dma_granularity)
+    planner = PathPlanner(Topology.full_mesh(4, with_host=False))
+    plan = planner.plan(0, 3, DMA_BYTES,
+                        granularity=dma_granularity(jnp.float32))
+    assert plan.num_paths > 1
+    nelems = DMA_BYTES // 4
+    inner = build_multipath_dma(plan, nelems, jnp.float32, 4,
+                                interpret=False)
+    fn = jax.jit(jax.shard_map(lambda x: inner(x[0])[None], mesh=mesh4,
+                               in_specs=P("dev"), out_specs=P("dev"),
+                               check_vma=False))
+    x = jax.ShapeDtypeStruct((4, nelems), jnp.float32,
+                             sharding=NamedSharding(mesh4, P("dev")))
+    compiled = fn.lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles(one_chip):
+    from repro.kernels.flash_attention.kernel import flash_attention_kernel
+    shape = jax.ShapeDtypeStruct((1, 32, 4096, 128), jnp.bfloat16,
+                                 sharding=one_chip)
+    fn = jax.jit(functools.partial(flash_attention_kernel, causal=True,
+                                   interpret=False))
+    compiled = fn.lower(shape, shape, shape).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_one_chip(compiled)

@@ -231,34 +231,8 @@ def test_build_schedule_is_graph_view(planner):
 
 # ----------------------- equal-graph acceptance test ------------------------
 
-def _sub_jaxprs(v):
-    if isinstance(v, jax.core.Jaxpr):
-        yield v
-    elif isinstance(v, jax.core.ClosedJaxpr):
-        yield v.jaxpr
-    elif isinstance(v, (list, tuple)):
-        for item in v:
-            yield from _sub_jaxprs(item)
-
-
-def _count_primitive(jaxpr, name):
-    count = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == name:
-            count += 1
-        for v in eqn.params.values():
-            for sub in _sub_jaxprs(v):
-                count += _count_primitive(sub, name)
-    return count
-
-
-def _count_ppermutes(fn, *abstract_args):
-    closed = jax.make_jaxpr(fn)(*abstract_args)
-    return _count_primitive(closed.jaxpr, "ppermute")
-
-
 @pytest.mark.parametrize("window", [1, 2])
-def test_equal_graph_invariant_single(topo, window):
+def test_equal_graph_invariant_single(topo, window, count_eqns):
     """ACCEPTANCE: the model's node count equals the number of ``ppermute``
     ops actually traced for the identical plan — the cost model and the
     executable are views of ONE graph."""
@@ -267,13 +241,13 @@ def test_equal_graph_invariant_single(topo, window):
     plan = eng.plan_for(0, 1, 4096, max_paths=3, num_chunks=4)
     graph, _ = eng._group_graph((plan,), window)
     fn = eng._build_group_fn(graph, (4,))
-    traced = _count_ppermutes(fn, jax.ShapeDtypeStruct(
-        (window, eng.num_devices, 4096), jnp.float32))
+    traced = count_eqns(fn, (jax.ShapeDtypeStruct(
+        (window, eng.num_devices, 4096), jnp.float32),), "ppermute")
     assert traced == graph.num_nodes
     assert graph.num_nodes == window * plan.num_nodes
 
 
-def test_equal_graph_invariant_group(topo):
+def test_equal_graph_invariant_group(topo, count_eqns):
     sess = CommSession(CommConfig(multipath_threshold=256), topology=topo)
     eng = sess.engine
     group = eng.plan_group_for([(0, 1, 1024, jnp.float32),
@@ -284,7 +258,7 @@ def test_equal_graph_invariant_group(topo):
     abstracts = [jax.ShapeDtypeStruct((1, eng.num_devices, n), dt)
                  for n, dt in ((1024, jnp.float32), (2048, jnp.float32),
                                (512, jnp.int32))]
-    assert _count_ppermutes(fn, *abstracts) == graph.num_nodes
+    assert count_eqns(fn, abstracts, "ppermute") == graph.num_nodes
     assert graph.num_nodes == sum(p.num_nodes for p in group.plans)
 
 
