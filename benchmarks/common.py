@@ -12,9 +12,6 @@ import jax  # noqa: E402
 
 MiB = 1 << 20
 
-SIZES_PUT = [1, 4, 16, 64, 128, 256, 512]          # MiB (paper Fig. 6)
-SIZES_OMB = [1, 4, 8, 16, 32, 64]                  # MiB (paper Fig. 7-10)
-EXEC_SIZES = [1, 4, 16]                            # MiB actually executed
 #: Chunk-interleaving schedulers swept by bench_graph_overhead (the
 #: ``--schedule`` axis; ``run.py --schedule NAME`` narrows it in place).
 SCHEDULES = ["round_robin", "depth_first", "critical_path", "overlap",

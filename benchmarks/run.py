@@ -2,9 +2,6 @@
 
 Prints ``name,us_per_call,derived`` CSV:
 
-* bench_put_bw         → paper Fig. 6   (UCX Put bandwidth)
-* bench_omb_bw         → paper Fig. 7/8 (OMB BW, windows 1/4/16)
-* bench_omb_bibw       → paper Fig. 9/10 (OMB bidirectional BW + groups)
 * bench_jacobi         → paper Fig. 12  (Jacobi solver speedup + halo group)
 * bench_graph_overhead → paper Fig. 13/14 (plan lifecycle costs)
 * bench_calibration    → DESIGN.md §4.4c (model error, cold vs fitted)
@@ -27,9 +24,6 @@ from benchmarks import common  # noqa: F401 — pins device count first
 def _apply_smoke() -> None:
     # In-place so modules that did ``from benchmarks.common import
     # SIZES_*`` see the shrunken sweeps.
-    common.SIZES_PUT[:] = [1, 4]
-    common.SIZES_OMB[:] = [1, 4]
-    common.EXEC_SIZES[:] = [1]
     common.DISPATCH_CHUNKS[:] = common.DISPATCH_CHUNKS[:1]
 
 
@@ -37,13 +31,12 @@ def collect() -> list:
     from benchmarks import (bench_calibration, bench_collectives,
                             bench_dispatch, bench_faults,
                             bench_graph_overhead, bench_jacobi,
-                            bench_omb_bibw, bench_omb_bw, bench_put_bw,
                             bench_step_capture)
 
     rows = []
-    for mod in (bench_put_bw, bench_omb_bw, bench_omb_bibw, bench_jacobi,
-                bench_graph_overhead, bench_dispatch, bench_calibration,
-                bench_step_capture, bench_collectives, bench_faults):
+    for mod in (bench_jacobi, bench_graph_overhead, bench_dispatch,
+                bench_calibration, bench_step_capture, bench_collectives,
+                bench_faults):
         rows.extend(mod.run())
     return rows
 

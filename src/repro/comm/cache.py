@@ -130,22 +130,14 @@ class CompiledPlan:
         self.lifecycle.total_launch_ns += time.perf_counter_ns() - t0
         return out
 
-    def timed_call(self, *args) -> tuple[Any, int, int]:
-        """Blocking launch that splits the wall time into ``(out,
-        launch_ns, execute_ns)`` for telemetry attribution (§4.4c):
-        launch is dispatch-until-control-returns, execute is the
-        ``block_until_ready`` tail. Lifecycle accounting is preserved
-        identically to ``__call__`` (one launch, total = launch +
-        execute), so the two entry points are interchangeable for every
-        stats invariant."""
+    def wait(self, out):
+        """Block until ``out`` of a :meth:`dispatch` is ready; the wait
+        counts toward the launch's lifecycle time, as in a blocking
+        call."""
         t0 = time.perf_counter_ns()
-        out = self.compiled(*args)
-        t1 = time.perf_counter_ns()
         jax.block_until_ready(out)
-        t2 = time.perf_counter_ns()
-        self.lifecycle.launches += 1
-        self.lifecycle.total_launch_ns += t2 - t0
-        return out, t1 - t0, t2 - t1
+        self.lifecycle.total_launch_ns += time.perf_counter_ns() - t0
+        return out
 
 
 def compile_plan(key: Hashable, fn: Callable, abstract_args: tuple,

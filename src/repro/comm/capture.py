@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from repro.comm.graph import (BUFFER_EDGE, HOP_EDGE, ComputeNode, CopyNode,
                               DepEdge, TransferGraph)
+from repro.comm.telemetry import spanned
 
 
 @dataclasses.dataclass(frozen=True)
@@ -419,13 +420,15 @@ class CapturedStep:
         return self.engine.resolve_step(
             self, schedule if schedule is not None else self.schedule)
 
+    @spanned("step")
     def __call__(self, *arrays, schedule: str | None = None,
                  block: bool = True) -> list[jax.Array]:
         """Run one captured iteration as ONE dispatch; ``arrays`` align
         with the capture's declared inputs (sharded inputs are global
         ``(num_devices, *local)``; replicated inputs are bare local
         arrays). Preserves eager numerics — the kernels are the same
-        functions, receptions join by exact zero-sum."""
+        functions, receptions join by exact zero-sum. One ``comm.step``
+        span holds the call."""
         return self.engine.run_step(
             self, arrays,
             schedule=schedule if schedule is not None else self.schedule,

@@ -52,7 +52,7 @@ import os
 import re
 import time
 from collections import OrderedDict
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import Sequence
 
 import jax
@@ -70,7 +70,7 @@ from repro.comm.passes import AutoSchedule, GraphPass, apply_schedule
 from repro.comm.plan import TransferGroup, TransferPlan, TransferRequest
 from repro.comm.planner import PathPlanner
 from repro.comm.telemetry import (DispatchSample, StageTimings,
-                                  TimelineRecorder)
+                                  TimelineRecorder, span, tracing)
 from repro.core.pipelining import validate_plan
 from repro.core.topology import HOST, Topology
 
@@ -133,6 +133,17 @@ class _StepEntry:
     schedule: str
     program: StepCapture
     outputs: tuple
+
+
+@partial(jax.jit, static_argnums=1)
+def comm_extract(y: jax.Array, dst: int) -> jax.Array:
+    """The received message out of a transfer program's output: window 0
+    of device ``dst``'s row, replicated. One named program, so that the
+    profiler shows the extraction as ``jit_comm_extract``. A gather, as
+    eager indexing of the sharded output takes it: on a mesh that is one
+    all-reduce, where a slice (``y[0, dst]`` under jit) adds a
+    collective-permute of the whole message."""
+    return jnp.take(y[0], dst, axis=0)
 
 
 def plan_signature(plan: TransferPlan) -> tuple:
@@ -413,24 +424,20 @@ class MultiPathTransfer:
         plan get distinct entries and can never cross-serve
         executables) — plus the concrete schedule name that was chosen.
         The emitter owns no ordering of its own. ``stages`` (telemetry
-        only) receives the lower/schedule wall-time attribution.
+        only) receives the lower/schedule wall-time attribution; both
+        stages are ``comm.lower`` / ``comm.schedule`` spans.
         """
         for p in plans:
             _check_executable(p)
-        t0 = time.perf_counter_ns()
-        graph = lower(TransferGroup(tuple(plans), self.topology.name),
-                      window)
-        t1 = time.perf_counter_ns()
+        with span("lower", stages):
+            graph = lower(TransferGroup(tuple(plans), self.topology.name),
+                          window)
         sched = self.schedule if schedule is None else schedule
-        if isinstance(sched, str):
-            out = _scheduled_graph(graph, sched, self.topology,
-                                   self.topology.epoch)
-        else:
-            out = apply_schedule(graph, sched, self.topology)
-        if stages is not None:
-            stages.lower_ns = t1 - t0
-            stages.schedule_ns = time.perf_counter_ns() - t1
-        return out
+        with span("schedule", stages):
+            if isinstance(sched, str):
+                return _scheduled_graph(graph, sched, self.topology,
+                                        self.topology.epoch)
+            return apply_schedule(graph, sched, self.topology)
 
     def _count_schedule(self, chosen: str) -> None:
         self.schedule_counts[chosen] = self.schedule_counts.get(chosen,
@@ -450,7 +457,8 @@ class MultiPathTransfer:
                              check_vma=False)
 
     def _compile_group(self, key: GroupKey, graph: TransferGraph,
-                       shapes: Sequence[tuple[int, object]]) -> CompiledPlan:
+                       shapes: Sequence[tuple[int, object]],
+                       stages: StageTimings | None = None) -> CompiledPlan:
         abstracts = tuple(
             jax.ShapeDtypeStruct((key.window, self.num_devices, nelems),
                                  dtype, sharding=self._sharding)
@@ -468,8 +476,9 @@ class MultiPathTransfer:
             # set); safe because the dispatch path rebuilds operands
             # every launch and never touches them again.
             jit_kwargs["donate_argnums"] = tuple(range(len(shapes)))
-        return compile_plan(key, fn, abstracts, num_nodes=graph.num_nodes,
-                            **jit_kwargs)
+        with span("compile", stages):
+            return compile_plan(key, fn, abstracts,
+                                num_nodes=graph.num_nodes, **jit_kwargs)
 
     def _group_key(self, graph: TransferGraph, plans: Sequence[TransferPlan],
                    shapes: Sequence[tuple[int, object]], window: int,
@@ -505,7 +514,8 @@ class MultiPathTransfer:
         launch staging is one fused kernel producing the sharded
         ``(window, ndev, nelems)`` operand instead of a fresh zero-fill +
         scatter + resharding of the whole array (the old per-launch
-        O(window·ndev·nelems) host-side cost).
+        O(window·ndev·nelems) host-side cost). The program is named
+        ``comm_stage`` (``jit_comm_stage`` in a profile).
         """
         key = (window, nelems, str(jnp.dtype(dtype)), src)
         fn = self._staging.get(key)
@@ -514,10 +524,10 @@ class MultiPathTransfer:
                 jnp.zeros((window, self.num_devices, nelems), dtype),
                 self._sharding)
 
-            def stage(m, _zeros=zeros):
+            def comm_stage(m, _zeros=zeros):
                 return _zeros.at[:, src].set(m)
 
-            fn = jax.jit(stage, out_shardings=self._sharding)
+            fn = jax.jit(comm_stage, out_shardings=self._sharding)
             # Warm the staging executable once at pool-insertion time so
             # steady-state `staging_ns` measures operand builds, not the
             # one-time jit compile (that is first-dispatch setup cost).
@@ -532,11 +542,15 @@ class MultiPathTransfer:
 
     def _launch(self, entry: FastPathEntry, messages: Sequence[jax.Array],
                 *, block: bool) -> list[jax.Array]:
-        """Stage operands (pooled) and launch the compiled program ONCE.
+        """Place and stage operands (pooled), launch the compiled program
+        ONCE and extract each received message (``comm_extract``): the
+        spans ``comm.place``, ``comm.stage``, ``comm.launch``, with
+        ``block`` ``comm.execute``, and ``comm.extract``, kept while a
+        profiler trace records or telemetry is on.
 
-        When telemetry is enabled the launch is split into dispatch vs
-        execute (``CompiledPlan.timed_call``) and the finished
-        :class:`~repro.comm.telemetry.StageTimings` is recorded as one
+        When telemetry is enabled the stages' times fill the pending
+        :class:`~repro.comm.telemetry.StageTimings` (placement and staging
+        both count as ``staging``), recorded as one
         :class:`~repro.comm.telemetry.DispatchSample`; lifecycle
         accounting is identical either way.
         """
@@ -545,39 +559,106 @@ class MultiPathTransfer:
         window = entry.graph.window
         stagers = [self._stage_fn(window, m.shape[0], m.dtype, p.src)
                    for m, p in zip(messages, entry.plans)]
-        t0 = time.perf_counter_ns()
+        compiled = entry.compiled
         # A message committed to one device (its source, say) cannot enter
         # a program over the whole mesh as it is: place it replicated, as
         # JAX does implicitly for an uncommitted one.
-        xs = [stage(jax.device_put(m, self._replicated))
-              for stage, m in zip(stagers, messages)]
-        staging = time.perf_counter_ns() - t0
-        self.staging_ns += staging
-        compiled = entry.compiled
-        compiled.lifecycle.staging_ns += staging
-        if stages is None:
+        t0 = time.perf_counter_ns()
+        if stages is None and not tracing():
+            # Nothing to record: the same stages without their spans, which
+            # cost about a microsecond each on a CPU host's send path.
+            xs = [stage(jax.device_put(m, self._replicated))
+                  for stage, m in zip(stagers, messages)]
+            staging = time.perf_counter_ns() - t0
             ys = compiled(*xs) if block else compiled.dispatch(*xs)
+            out = [comm_extract(y, p.dst) for y, p in zip(ys, entry.plans)]
         else:
+            with span("place"):
+                xs = [jax.device_put(m, self._replicated) for m in messages]
+            with span("stage"):
+                xs = [stage(x) for stage, x in zip(stagers, xs)]
+            staging = time.perf_counter_ns() - t0
+            ys = self._execute(compiled, xs, stages, block)
+            with span("extract"):
+                out = [comm_extract(y, p.dst)
+                       for y, p in zip(ys, entry.plans)]
+        self.staging_ns += staging
+        compiled.lifecycle.staging_ns += staging
+        if stages is not None:
             stages.staging_ns = staging
-            if block:
-                ys, stages.launch_ns, stages.execute_ns = (
-                    compiled.timed_call(*xs))
-            else:
-                t1 = time.perf_counter_ns()
-                ys = compiled.dispatch(*xs)
-                stages.launch_ns = time.perf_counter_ns() - t1
-            routes = tuple(
-                tuple((pa.route.directional_links(), pa.nbytes,
-                       pa.num_chunks) for pa in p.paths)
-                for p in entry.plans)
-            self.telemetry.record(DispatchSample(
-                routes=routes,
-                nbytes=sum(p.nbytes for p in entry.plans),
-                num_nodes=entry.graph.num_nodes, window=window,
-                schedule=entry.schedule, stages=stages,
-                fastpath_hit=hit))
+            self._record(entry, stages, hit)
         self.dispatches += 1
-        return [y[0, p.dst] for y, p in zip(ys, entry.plans)]
+        return out
+
+    def _execute(self, compiled: CompiledPlan, xs: Sequence[jax.Array],
+                 stages: StageTimings | None, block: bool):
+        """Launch ``compiled`` once (``comm.launch``) and, with ``block``,
+        wait for it (``comm.execute``); the lifecycle counts the launch
+        and the wait."""
+        with span("launch", stages):
+            ys = compiled.dispatch(*xs)
+        if block:
+            with span("execute", stages):
+                compiled.wait(ys)
+        return ys
+
+    def _record(self, entry, stages: StageTimings, hit: bool,
+                compute: tuple = ()) -> None:
+        """Record one finished dispatch of ``entry`` as a
+        :class:`~repro.comm.telemetry.DispatchSample`."""
+        routes = tuple(
+            tuple((pa.route.directional_links(), pa.nbytes, pa.num_chunks)
+                  for pa in p.paths)
+            for p in entry.plans)
+        self.telemetry.record(DispatchSample(
+            routes=routes, nbytes=sum(p.nbytes for p in entry.plans),
+            num_nodes=entry.graph.num_nodes, window=entry.graph.window,
+            schedule=entry.schedule, stages=stages, fastpath_hit=hit,
+            compute=compute))
+
+    def _fast_entry(self, sig: tuple, epoch: tuple, recompile):
+        """The fast-path entry for ``sig`` under ``epoch``, made ready to
+        launch, or None on a miss. A hit still consults the plan cache by
+        stored key so LRU stats/recency stay coherent; an executable
+        evicted under us is rebuilt by ``recompile(entry)`` without
+        re-planning. ``validate="always"`` re-validates the plans and
+        graph here (§4.5)."""
+        entry = self._fastpath.get(sig, epoch)
+        if entry is None:
+            return None
+        compiled = self.cache.get(entry.key)
+        if compiled is None:   # evicted under us: recompile only
+            compiled = recompile(entry)
+            self.cache.put(entry.key, compiled)
+        entry.compiled = compiled
+        if self.validate == "always":
+            for p in entry.plans:
+                validate_plan(p)
+            entry.graph.validate(
+                {i: p.nbytes for i, p in enumerate(entry.plans)},
+                cross_flow_exclusive=False)
+        compiled.lifecycle.fastpath_hits += 1
+        self._count_schedule(entry.schedule)
+        self._pending_hit = True
+        return entry
+
+    def _resolving(self, body, *args):
+        """Resolve the dispatch about to launch by ``body(stages, *args)``,
+        with fresh :class:`~repro.comm.telemetry.StageTimings` left
+        pending for the launch when telemetry records (None otherwise).
+        While there is anything to record, the resolution is one
+        ``comm.resolve`` span, whose argument ``hit`` says whether the fast
+        path served it."""
+        tel = self.telemetry
+        stages = (StageTimings() if tel is not None and tel.enabled
+                  else None)
+        self._pending_stages, self._pending_hit = stages, False
+        if stages is None and not tracing():
+            return body(stages, *args)
+        with span("resolve") as resolve:
+            entry = body(stages, *args)
+            resolve.note(hit=self._pending_hit)
+        return entry
 
     def _resolve(self, specs: Sequence[tuple], *, window: int,
                  max_paths: int | None, num_chunks: int | None,
@@ -587,21 +668,27 @@ class MultiPathTransfer:
 
         Fast path (hit): one dict lookup against the epoch-stamped
         :class:`FastPathCache` — planner, ``lower()``, scheduler pass,
-        validation, and digest are all skipped; the plan cache is still
-        consulted by stored key so LRU stats/recency stay coherent (and
-        an evicted executable is recompiled from the memoized graph
-        without re-planning). Slow path (miss): the full pipeline, then
-        the resolution is memoized under the current planner epoch.
-        Custom :class:`GraphPass` objects bypass the fast path — their
-        identity is not a stable signature.
+        validation, and digest are all skipped (:meth:`_fast_entry`).
+        Slow path (miss): the full pipeline, then the resolution is
+        memoized under the current planner epoch. Custom
+        :class:`GraphPass` objects bypass the fast path — their identity
+        is not a stable signature. Spans as :meth:`_resolving` says; a
+        miss adds ``comm.plan``, ``comm.lower``, ``comm.schedule`` and,
+        when it builds a program, ``comm.compile``.
         """
+        return self._resolving(self._resolve_group, specs, window,
+                               max_paths, num_chunks, exclusive, schedule,
+                               single)
+
+    def _resolve_group(self, stages: StageTimings | None,
+                       specs: Sequence[tuple], window: int,
+                       max_paths: int | None, num_chunks: int | None,
+                       exclusive: bool, schedule: str | GraphPass | None,
+                       single: bool) -> FastPathEntry:
+        """:meth:`_resolve` under the pending ``stages``."""
         sched = self.schedule if schedule is None else schedule
         sched_name = sched if isinstance(sched, str) else None
         use_fast = self.fastpath and sched_name is not None
-        tel = self.telemetry
-        stages = (StageTimings() if tel is not None and tel.enabled
-                  else None)
-        self._pending_stages, self._pending_hit = stages, False
         shapes = [(nelems, jnp.dtype(dtype))
                   for (_, _, nelems, dtype) in specs]
         sig = epoch = None
@@ -610,53 +697,28 @@ class MultiPathTransfer:
                 "plan" if single else "plan_group", specs, window,
                 sched_name, max_paths, num_chunks, exclusive)
             epoch = self.planner.epoch
-            entry = self._fastpath.get(sig, epoch)
+            entry = self._fast_entry(sig, epoch, lambda e: (
+                self._compile_group(e.key, e.graph, shapes, stages)))
             if entry is not None:
-                compiled = self.cache.get(entry.key)
-                if compiled is None:   # evicted under us: recompile only
-                    compiled = self._compile_group(entry.key, entry.graph,
-                                                   shapes)
-                    self.cache.put(entry.key, compiled)
-                    if stages is not None:
-                        stages.compile_ns = compiled.lifecycle.build_ns
-                entry.compiled = compiled
-                if self.validate == "always":
-                    for p in entry.plans:
-                        validate_plan(p)
-                    entry.graph.validate(
-                        {i: p.nbytes for i, p in enumerate(entry.plans)},
-                        cross_flow_exclusive=False)
-                compiled.lifecycle.fastpath_hits += 1
-                self._count_schedule(entry.schedule)
-                self._pending_hit = True
                 return entry
-        t0 = time.perf_counter_ns()
-        if single:
-            (src, dst, nelems, dtype) = specs[0]
-            plans: tuple[TransferPlan, ...] = (self.plan_for(
-                src, dst, nelems, dtype, max_paths=max_paths,
-                num_chunks=num_chunks),)
-        else:
-            plans = self.plan_group_for(specs, max_paths=max_paths,
-                                        num_chunks=num_chunks,
-                                        exclusive=exclusive).plans
-        if stages is not None:
-            stages.plan_ns = time.perf_counter_ns() - t0
+        with span("plan", stages):
+            if single:
+                (src, dst, nelems, dtype) = specs[0]
+                plans: tuple[TransferPlan, ...] = (self.plan_for(
+                    src, dst, nelems, dtype, max_paths=max_paths,
+                    num_chunks=num_chunks),)
+            else:
+                plans = self.plan_group_for(
+                    specs, max_paths=max_paths, num_chunks=num_chunks,
+                    exclusive=exclusive).plans
         graph, chosen = self._group_graph(plans, window, sched,
                                           stages=stages)
         self._count_schedule(chosen)
         key = self._group_key(graph, plans, shapes, window,
                               donated=self._donate)
-        built: list[CompiledPlan] = []
-
-        def _builder() -> CompiledPlan:
-            c = self._compile_group(key, graph, shapes)
-            built.append(c)
-            return c
-
-        compiled = self.cache.get_or_build(key, _builder)
-        if stages is not None and built:
-            stages.compile_ns = compiled.lifecycle.build_ns
+        compiled = self.cache.get_or_build(
+            key, lambda: self._compile_group(key, graph, shapes,
+                                             stages))
         entry = FastPathEntry(plans=tuple(plans), graph=graph,
                               digest=key.digest, key=key,
                               compiled=compiled, schedule=chosen)
@@ -728,7 +790,8 @@ class MultiPathTransfer:
         return tuple(abstracts)
 
     def _compile_step(self, key: GroupKey, graph: TransferGraph,
-                      program: StepCapture, outputs: tuple) -> CompiledPlan:
+                      program: StepCapture, outputs: tuple,
+                      stages: StageTimings | None = None) -> CompiledPlan:
         """Compile one captured step (never donated: callers legitimately
         reuse input arrays, e.g. re-running a step on the same batch)."""
         fn = self._build_step_fn(program, graph, outputs)
@@ -736,8 +799,9 @@ class MultiPathTransfer:
         self.edges_compiled += graph.num_edges
         self.copy_nodes_compiled += graph.num_copy_nodes
         self.compute_nodes_compiled += graph.num_compute_nodes
-        return compile_plan(key, fn, self._step_abstracts(program),
-                            num_nodes=graph.num_nodes)
+        with span("compile", stages):
+            return compile_plan(key, fn, self._step_abstracts(program),
+                                num_nodes=graph.num_nodes)
 
     def resolve_step(self, step: CapturedStep,
                      schedule: str | GraphPass | None = None) -> _StepEntry:
@@ -749,50 +813,34 @@ class MultiPathTransfer:
         §4.5 validation (inside lowering) → compile, keyed on the
         scheduled graph digest + capture signature + per-kernel compute
         identity, then memoizes. Two schedules of the same capture
-        digest apart and never cross-serve executables.
+        digest apart and never cross-serve executables. Spans as in
+        :meth:`_resolve`.
         """
+        return self._resolving(self._resolve_step, step, schedule)
+
+    def _resolve_step(self, stages: StageTimings | None, step: CapturedStep,
+                      schedule: str | GraphPass | None) -> _StepEntry:
+        """:meth:`resolve_step` under the pending ``stages``."""
         program = step.capture
         sched = self.schedule if schedule is None else schedule
         sched_name = sched if isinstance(sched, str) else None
         use_fast = self.fastpath and sched_name is not None
-        tel = self.telemetry
-        stages = (StageTimings() if tel is not None and tel.enabled
-                  else None)
-        self._pending_stages, self._pending_hit = stages, False
         sig = epoch = None
         if use_fast:
             sig = ("capture_step", program.signature(), step.outputs,
                    sched_name, self.num_devices)
             epoch = self.planner.epoch
-            entry = self._fastpath.get(sig, epoch)
+            entry = self._fast_entry(sig, epoch, lambda e: (
+                self._compile_step(e.key, e.graph, e.program,
+                                   e.outputs, stages)))
             if entry is not None:
-                compiled = self.cache.get(entry.key)
-                if compiled is None:   # evicted under us: recompile only
-                    compiled = self._compile_step(
-                        entry.key, entry.graph, entry.program,
-                        entry.outputs)
-                    self.cache.put(entry.key, compiled)
-                    if stages is not None:
-                        stages.compile_ns = compiled.lifecycle.build_ns
-                entry.compiled = compiled
-                if self.validate == "always":
-                    for p in entry.plans:
-                        validate_plan(p)
-                    entry.graph.validate(
-                        {i: p.nbytes for i, p in enumerate(entry.plans)},
-                        cross_flow_exclusive=False)
-                compiled.lifecycle.fastpath_hits += 1
-                self._count_schedule(entry.schedule)
-                self._pending_hit = True
                 return entry
-        t0 = time.perf_counter_ns()
-        graph, plans = lower_step(program, self.plan_group_for,
-                                  self.topology.name)
-        t1 = time.perf_counter_ns()
-        scheduled, chosen = apply_schedule(graph, sched, self.topology)
-        if stages is not None:
-            stages.lower_ns = t1 - t0
-            stages.schedule_ns = time.perf_counter_ns() - t1
+        with span("lower", stages):
+            graph, plans = lower_step(program, self.plan_group_for,
+                                      self.topology.name)
+        with span("schedule", stages):
+            scheduled, chosen = apply_schedule(graph, sched,
+                                               self.topology)
         self._count_schedule(chosen)
         compute_id = tuple((n.kernel, n.flops, n.cost_ns)
                            for n in scheduled.nodes
@@ -801,18 +849,12 @@ class MultiPathTransfer:
                        entries=(program.signature(), step.outputs)
                        + compute_id,
                        window=1, num_devices=self.num_devices)
-        built: list[CompiledPlan] = []
-
-        def _builder() -> CompiledPlan:
-            c = self._compile_step(key, scheduled, program, step.outputs)
-            built.append(c)
-            return c
-
-        compiled = self.cache.get_or_build(key, _builder)
-        if stages is not None and built:
-            stages.compile_ns = compiled.lifecycle.build_ns
-        entry = _StepEntry(plans=plans, graph=scheduled, digest=key.digest,
-                           key=key, compiled=compiled, schedule=chosen,
+        compiled = self.cache.get_or_build(
+            key, lambda: self._compile_step(key, scheduled, program,
+                                            step.outputs, stages))
+        entry = _StepEntry(plans=plans, graph=scheduled,
+                           digest=key.digest, key=key,
+                           compiled=compiled, schedule=chosen,
                            program=program, outputs=step.outputs)
         if use_fast:
             self._fastpath.put(sig, epoch, entry)
@@ -823,7 +865,9 @@ class MultiPathTransfer:
         """Stage the step inputs (device_put onto the declared shardings;
         staging a whole iteration's operands is dominated by the step
         itself, so inputs are not pooled like message staging) and launch
-        the compiled whole-iteration program ONCE."""
+        the compiled whole-iteration program ONCE: the spans
+        ``comm.place``, ``comm.launch`` and, with ``block``,
+        ``comm.execute``."""
         stages, hit = self._pending_stages, self._pending_hit
         self._pending_stages, self._pending_hit = None, False
         program = entry.program
@@ -831,48 +875,32 @@ class MultiPathTransfer:
             raise ValueError(f"captured step takes {len(program.inputs)} "
                              f"input arrays, got {len(arrays)}")
         t0 = time.perf_counter_ns()
-        xs = []
-        for bid, arr in zip(program.inputs, arrays):
-            spec = program.buffers[bid]
-            arr = jnp.asarray(arr, jnp.dtype(spec.dtype))
-            want = (spec.shape if spec.replicated
-                    else (self.num_devices,) + spec.shape)
-            if tuple(arr.shape) != want:
-                raise ValueError(
-                    f"input for buffer {bid} must have shape {want} "
-                    f"({'replicated' if spec.replicated else 'sharded'}), "
-                    f"got {tuple(arr.shape)}")
-            sh = NamedSharding(self.mesh, P() if spec.replicated
-                               else P(self.axis_name))
-            xs.append(jax.device_put(arr, sh))
+        with span("place"):
+            xs = []
+            for bid, arr in zip(program.inputs, arrays):
+                spec = program.buffers[bid]
+                arr = jnp.asarray(arr, jnp.dtype(spec.dtype))
+                want = (spec.shape if spec.replicated
+                        else (self.num_devices,) + spec.shape)
+                if tuple(arr.shape) != want:
+                    raise ValueError(
+                        f"input for buffer {bid} must have shape {want} "
+                        f"({'replicated' if spec.replicated else 'sharded'}"
+                        f"), got {tuple(arr.shape)}")
+                sh = NamedSharding(self.mesh, P() if spec.replicated
+                                   else P(self.axis_name))
+                xs.append(jax.device_put(arr, sh))
         staging = time.perf_counter_ns() - t0
         self.staging_ns += staging
         compiled = entry.compiled
         compiled.lifecycle.staging_ns += staging
-        if stages is None:
-            ys = compiled(*xs) if block else compiled.dispatch(*xs)
-        else:
+        if stages is not None:
             stages.staging_ns = staging
-            if block:
-                ys, stages.launch_ns, stages.execute_ns = (
-                    compiled.timed_call(*xs))
-            else:
-                t1 = time.perf_counter_ns()
-                ys = compiled.dispatch(*xs)
-                stages.launch_ns = time.perf_counter_ns() - t1
-            routes = tuple(
-                tuple((pa.route.directional_links(), pa.nbytes,
-                       pa.num_chunks) for pa in p.paths)
-                for p in entry.plans)
-            compute = tuple((n.kernel, n.flops, n.cost_ns)
-                            for n in entry.graph.nodes
-                            if isinstance(n, ComputeNode))
-            self.telemetry.record(DispatchSample(
-                routes=routes,
-                nbytes=sum(p.nbytes for p in entry.plans),
-                num_nodes=entry.graph.num_nodes, window=1,
-                schedule=entry.schedule, stages=stages,
-                fastpath_hit=hit, compute=compute))
+        ys = self._execute(compiled, xs, stages, block)
+        if stages is not None:
+            self._record(entry, stages, hit, tuple(
+                (n.kernel, n.flops, n.cost_ns) for n in entry.graph.nodes
+                if isinstance(n, ComputeNode)))
         self.dispatches += 1
         return list(ys)
 

@@ -58,7 +58,7 @@ from repro.comm.passes import GraphPass
 from repro.comm.plan import TransferPlan
 from repro.comm.planner import PathPlanner
 from repro.comm.policy import PathPolicy, make_policy
-from repro.comm.telemetry import TimelineRecorder
+from repro.comm.telemetry import TimelineRecorder, spanned
 from repro.core.topology import Topology
 
 
@@ -239,6 +239,7 @@ class CommSession:
         return self.planner.tune(src, dst, nbytes, **kwargs)
 
     # -- point-to-point -----------------------------------------------------
+    @spanned("send")
     def send(self, x: jax.Array, src: int, dst: int, *,
              window: int | None = None, max_paths: int | None = None,
              num_chunks: int | None = None,
@@ -247,7 +248,8 @@ class CommSession:
         """Send 1-D ``x`` from device ``src`` to ``dst``; returns the
         received message. Compiled plans are cached (src, dst, size,
         config, dispatch schedule). ``schedule`` overrides the session's
-        chunk-interleaving scheduler for this call (DESIGN.md §2.2).
+        chunk-interleaving scheduler for this call (DESIGN.md §2.2). One
+        ``comm.send`` span holds the call.
         """
         return self.engine.transfer(
             x, src, dst, window=self.config.window if window is None
@@ -272,6 +274,7 @@ class CommSession:
             max_paths=max_paths, num_chunks=num_chunks, schedule=schedule)
         return fwd, rev
 
+    @spanned("exchange")
     def exchange(self, items, *, window: int | None = None,
                  max_paths: int | None = None,
                  num_chunks: int | None = None,
@@ -293,7 +296,7 @@ class CommSession:
         send — ``nbytes must be positive`` would otherwise reject them).
         ``exclusive=True`` demands group-level link exclusivity and raises
         if the topology cannot provide it. Returns the received arrays,
-        aligned with ``items``.
+        aligned with ``items``. One ``comm.exchange`` span holds the call.
         """
         items = list(items)
         results: list[jax.Array | None] = [None] * len(items)

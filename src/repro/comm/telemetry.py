@@ -17,13 +17,23 @@ When the recorder is disabled (the default; enable with
 per dispatch, which is what keeps the §2.3 fast path's setup cost
 unchanged — the guarantee ``benchmarks/bench_calibration.py`` and the
 CI smoke assertion watch.
+
+The same stages are spans on the profiler's clock: :func:`span` opens a
+``jax.profiler.TraceAnnotation`` named ``comm.<stage>`` and, for a
+dispatch that carries :class:`StageTimings`, adds the stage's wall time
+to its field, so one timer serves both the recorder and a
+``jax.profiler`` trace.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import time
 from collections import deque
 from typing import Iterable
+
+from jax.profiler import TraceAnnotation
 
 from repro.comm.config import _env_bool
 
@@ -44,27 +54,30 @@ class StageTimings:
     """Wall time of one dispatch attributed to pipeline stages, in ns.
 
     The attribution invariant: every field is measured around exactly one
-    stage of the §2.3 dispatch pipeline, so ``plan+lower+schedule+compile``
-    is the (fast-path-skippable) setup cost and ``staging+launch+execute``
-    the per-dispatch cost. Fast-path hits preserve zeros in the setup
-    fields — that is evidence, not a gap. Mutable on purpose: the engine
-    fills stages in as the dispatch proceeds, then freezes the result
-    into a :class:`DispatchSample`.
+    stage of the §2.3 dispatch pipeline (by :func:`span`), so
+    ``plan+lower+schedule+compile`` is the (fast-path-skippable) setup
+    cost and ``staging+launch+execute`` the per-dispatch cost. The
+    stages do not cover the whole dispatch: the fast-path lookup
+    (``comm.resolve`` around them) and the extraction of the received
+    message (``comm.extract``) are spans of their own with no field
+    here. Fast-path hits preserve zeros in the setup fields — that is
+    evidence, not a gap. Mutable on purpose: the engine fills stages in
+    as the dispatch proceeds, then freezes the result into a
+    :class:`DispatchSample`.
     """
 
     plan_ns: int = 0      # planner: route enumeration + path split
     lower_ns: int = 0     # graph lowering (plan -> copy-node DAG)
     schedule_ns: int = 0  # scheduler pass (§2.2 pipeline)
-    compile_ns: int = 0   # jit trace + lower + compile (build_ns)
-    staging_ns: int = 0   # pooled staging-buffer preparation
+    compile_ns: int = 0   # building and compiling the program
+    staging_ns: int = 0   # message placement + pooled staging write
     launch_ns: int = 0    # dispatch call until control returns
     execute_ns: int = 0   # block_until_ready tail after dispatch
 
     @property
     def total_ns(self) -> int:
-        """Sum over every stage — the invariant check that attribution
-        covers the dispatch: stages are disjoint, so their sum is the
-        attributed wall time."""
+        """Sum over every stage: the stages are disjoint, so their sum is
+        the attributed wall time (the invariant the fitter relies on)."""
         return (self.plan_ns + self.lower_ns + self.schedule_ns
                 + self.compile_ns + self.staging_ns + self.launch_ns
                 + self.execute_ns)
@@ -74,6 +87,102 @@ class StageTimings:
         contract that ``session.describe()`` / ``--json`` benchmark rows
         serialize."""
         return {name: getattr(self, f"{name}_ns") for name in STAGES}
+
+
+#: Span stage -> the :class:`StageTimings` field its wall time adds to.
+#: The other spans (``resolve``, ``place``, ``stage``, ``extract``,
+#: ``send``, ``exchange``, ``step``) have no field: the engine times
+#: placement and staging together, into ``staging``, with one clock pair
+#: of its own, since it counts them whether or not telemetry records.
+_FIELDS = {"plan": "plan_ns", "lower": "lower_ns",
+           "schedule": "schedule_ns", "compile": "compile_ns",
+           "launch": "launch_ns", "execute": "execute_ns"}
+
+#: True while a ``jax.profiler`` trace records (spans are kept then).
+tracing = TraceAnnotation.is_enabled
+_clock = time.perf_counter_ns
+
+
+class _Span:
+    __slots__ = ("_trace", "_timings", "_field", "_t0")
+
+    def __init__(self, trace, timings: StageTimings | None,
+                 field: str | None):
+        self._trace, self._timings, self._field = trace, timings, field
+
+    def __enter__(self):
+        if self._trace is not None:
+            self._trace.__enter__()
+        self._t0 = _clock()
+        return self
+
+    def __exit__(self, *exc):
+        if self._field is not None:
+            setattr(self._timings, self._field,
+                    getattr(self._timings, self._field)
+                    + _clock() - self._t0)
+        if self._trace is not None:
+            self._trace.__exit__(*exc)
+
+    def note(self, **args) -> None:
+        if self._trace is not None:
+            self._trace.set_metadata(**args)
+
+
+class _Untraced:
+    """What :func:`span` returns when there is nothing to record."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+    def note(self, **args) -> None:
+        pass
+
+
+_UNTRACED = _Untraced()
+
+
+def span(stage: str, timings: StageTimings | None = None):
+    """Context manager for one stage of a dispatch: a
+    ``jax.profiler.TraceAnnotation`` named ``comm.<stage>`` that also adds
+    the stage's wall time to its :class:`StageTimings` field when
+    ``timings`` is given.
+
+    ``note(**args)`` sets the span's arguments from inside the block
+    (``hit=`` on ``comm.resolve``). The observability contract: spans are
+    passive — they preserve dispatch behaviour exactly, and with the
+    profiler off and no timings to fill, a span is one check and a
+    shared no-op.
+    """
+    field = None if timings is None else _FIELDS.get(stage)
+    if tracing():
+        return _Span(TraceAnnotation("comm." + stage), timings, field)
+    if field is None:
+        return _UNTRACED
+    return _Span(None, timings, field)
+
+
+def spanned(stage: str):
+    """Decorator for a public call of the library: the whole call is one
+    ``comm.<stage>`` span while a profiler trace records, and a plain call
+    otherwise, after one check. Passive, as :func:`span` is: it preserves
+    the call's behaviour exactly."""
+    name = "comm." + stage
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            if not tracing():
+                return fn(*args, **kwargs)
+            with TraceAnnotation(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
 
 
 @dataclasses.dataclass(frozen=True)

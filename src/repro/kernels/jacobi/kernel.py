@@ -13,7 +13,6 @@ domain boundary, handled with Dirichlet zeros).
 
 from __future__ import annotations
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -36,11 +35,15 @@ def jacobi_sweep_kernel(ext: jax.Array, *, tile: int = TILE,
     """One sweep over a halo-extended block ``ext: (rows, W + 2)``.
 
     Returns the updated interior ``(rows, W)``. The three shifted views are
-    materialized outside (XLA fuses the slices into the pallas_call copies).
+    materialized outside the kernel, under the scope ``jacobi.views`` (XLA
+    fuses the slices into one copy). The ``pallas_call`` opens no scope of
+    its own: the innermost scope names the kernel's HLO instruction
+    (``jacobi_sweep.1`` under the ``ops`` wrapper's jit).
     """
     rows, wp2 = ext.shape
     w = wp2 - 2
-    left, center, right = ext[:, :-2], ext[:, 1:-1], ext[:, 2:]
+    with jax.named_scope("jacobi.views"):
+        left, center, right = ext[:, :-2], ext[:, 1:-1], ext[:, 2:]
     tile = min(tile, w)
     grid = (pl.cdiv(w, tile),)
     spec = pl.BlockSpec((rows, tile), lambda i: (0, i))

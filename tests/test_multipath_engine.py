@@ -37,6 +37,24 @@ def test_transfer_of_messages_committed_to_their_source(engine):
     np.testing.assert_array_equal(np.asarray(rev), np.asarray(b))
 
 
+@pytest.mark.parametrize("dst", [1, 6])
+def test_extraction_matches_eager_indexing(engine, dst):
+    """``comm_extract`` gives what eager ``y[0, dst]`` gives on the
+    engine's sharded ``(window, ndev, nelems)`` output: the same values,
+    replicated over the mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.comm.engine import comm_extract
+    n = engine.num_devices
+    y = jax.device_put(
+        jnp.arange(2 * n * 16, dtype=jnp.float32).reshape(2, n, 16),
+        NamedSharding(engine.mesh, P(None, engine.axis_name)))
+    got, eager = comm_extract(y, dst), y[0, dst]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(eager))
+    assert got.sharding.is_fully_replicated
+    assert got.sharding.is_equivalent_to(eager.sharding, 1)
+
+
 def test_bidirectional_group(engine):
     """Opposite-direction traffic is a 2-transfer group (the old
     ``bidirectional=True`` flag); BOTH receptions are returned."""

@@ -10,6 +10,7 @@ worker that never runs this file never loads the TPU library.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +84,25 @@ def test_engine_group_program_512mib(mesh4, pairs):
     assert text.count("collective-permute-start") >= sum(
         p.num_nodes for p in group.plans)
     _fits_one_chip(plan_cp.compiled)
+
+
+@pytest.mark.parametrize("dst", [1, 3])
+def test_extraction_program_512mib(mesh4, dst):
+    """``comm_extract`` on a 512 MiB transfer output: the message comes
+    back replicated on the four chips through one all-reduce and no other
+    collective, as the eager ``y[0, dst]`` (a gather) did, and needs no
+    temporary."""
+    from repro.comm.engine import comm_extract
+    y = jax.ShapeDtypeStruct((1, 4, MSG_ELEMS), jnp.float32,
+                             sharding=NamedSharding(mesh4, P(None, "dev")))
+    compiled = comm_extract.lower(y, dst).compile()
+    text = compiled.as_text()
+    collectives = re.findall(r"\b(all-reduce|collective-permute|all-gather|"
+                             r"all-to-all|reduce-scatter)(?:-start)?\(",
+                             text)
+    assert collectives == ["all-reduce"]
+    assert compiled.output_shardings.is_fully_replicated
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_jacobi_kernel_at_smoke_size(one_chip):
