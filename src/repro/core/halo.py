@@ -179,13 +179,15 @@ def jacobi_step(u: jax.Array, axis_name: str, *, multipath: bool = False,
     zero (Dirichlet) conditions at the global domain edge — matching the
     NVIDIA multi-GPU Jacobi reference the paper benchmarks.
 
+    With ``use_kernel`` the Pallas kernel reads ``u`` in place beside the
+    two halo columns; the ``jnp`` update sweeps the halo-extended block.
+
     Each part runs under a ``jax.named_scope`` that the profiler shows in
     its ops' ``tf_op`` path: ``jacobi.halo`` (the exchange),
     ``jacobi.edges`` (the Dirichlet zeros), ``jacobi.extend`` (the
-    halo-extended block) and ``jacobi.stencil`` (the update; with
-    ``use_kernel`` the kernel's shifted operands carry ``jacobi.views``
-    inside it). The names are metadata only: the compiled program is the
-    same without them.
+    halo-extended block, ``jnp`` path only) and ``jacobi.stencil`` (the
+    update). The names are metadata only: the compiled program is the same
+    without them.
     """
     with jax.named_scope("jacobi.halo"):
         left_halo, right_halo = halo_exchange_ring(
@@ -199,12 +201,14 @@ def jacobi_step(u: jax.Array, axis_name: str, *, multipath: bool = False,
         right_halo = jnp.where(i == n - 1, jnp.zeros_like(right_halo),
                                right_halo)
 
+    if use_kernel:
+        from repro.kernels.jacobi import ops as jacobi_ops
+        with jax.named_scope("jacobi.stencil"):
+            return jacobi_ops.jacobi_sweep(u, left_halo, right_halo)
+
     with jax.named_scope("jacobi.extend"):
         ext = jnp.concatenate([left_halo, u, right_halo], axis=1)
     with jax.named_scope("jacobi.stencil"):
-        if use_kernel:
-            from repro.kernels.jacobi import ops as jacobi_ops
-            return jacobi_ops.jacobi_sweep(ext)
         up = jnp.pad(ext[:-1, :], ((1, 0), (0, 0)))
         down = jnp.pad(ext[1:, :], ((0, 1), (0, 0)))
         return 0.25 * (ext[:, :-2] + ext[:, 2:] + up[:, 1:-1]

@@ -14,8 +14,15 @@ def _is_cpu() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def jacobi_sweep(ext: jax.Array, *, tile: int = 512,
+def jacobi_sweep(u: jax.Array, left_halo: jax.Array, right_halo: jax.Array,
+                 *, tile: int | None = None,
                  interpret: bool | None = None) -> jax.Array:
+    """One sweep of ``u: (rows, W)`` between its halo columns ``(rows, 1)``.
+
+    The tile comes from the shape (``kernel.sweep_tile``); ``tile``
+    overrides it, for tests.
+    """
     if interpret is None:
         interpret = _is_cpu()
-    return jacobi_sweep_kernel(ext, tile=tile, interpret=interpret)
+    return jacobi_sweep_kernel(u, left_halo, right_halo, tile=tile,
+                               interpret=interpret)

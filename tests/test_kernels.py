@@ -107,11 +107,48 @@ from repro.kernels.jacobi import ref as j_ref
 def test_jacobi_sweep(rows, w, tile, dtype):
     ext = jnp.asarray(
         np.random.RandomState(2).randn(rows, w + 2), dtype)
-    got = j_ops.jacobi_sweep(ext, tile=tile)
+    got = j_ops.jacobi_sweep(ext[:, 1:-1], ext[:, :1], ext[:, -1:],
+                             tile=tile)
     ref = j_ref.jacobi_sweep_ref(ext)
     tol = 1e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("rows,w,tile,dtype", [
+    (8, 700, 256, jnp.float32),      # ragged: not a multiple of 128 or T
+    (8, 1024, 1024, jnp.float32),    # exactly one tile
+    (8, 4096, 512, jnp.float32),     # neighbour blocks cross tile edges
+    (16, 512, 128, jnp.float32),     # one-vreg tiles
+    (8, 100, None, jnp.float32),     # narrower than a vreg
+    (8, 1 << 14, None, jnp.float32),  # the tile the shape picks
+    (16, 1000, 256, jnp.bfloat16),
+], ids=["ragged", "one_tile", "many_tiles", "vreg_tiles", "narrow",
+        "shape_tile", "bf16"])
+def test_jacobi_sweep_halos_bit_equal(rows, w, tile, dtype):
+    """Nonzero halos on both sides, bit for bit against the reference on
+    the halo-extended block."""
+    rng = np.random.RandomState(5)
+    u, lh, rh = (jnp.asarray(rng.randn(rows, n), dtype)
+                 for n in (w, 1, 1))
+    got = j_ops.jacobi_sweep(u, lh, rh, tile=tile)
+    ref = j_ref.jacobi_sweep_ref(jnp.concatenate([lh, u, rh], axis=1))
+    assert got.shape == u.shape and got.dtype == u.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(ref, np.float32))
+
+
+def test_jacobi_sweep_tile_rule():
+    """The tile fills the VMEM budget at the widths the benchmark sweeps,
+    and leaves a small grid its steps."""
+    from repro.kernels.jacobi.kernel import sweep_tile
+    assert sweep_tile(8, 1 << 26, jnp.float32) == 1 << 15
+    assert sweep_tile(8, 1 << 26, jnp.bfloat16) == 1 << 15
+    assert sweep_tile(8, 1 << 16, jnp.float32) == 1 << 13
+    assert sweep_tile(8, 100, jnp.float32) == 128
+    with pytest.raises(ValueError, match="multiple of 128"):
+        j_ops.jacobi_sweep(jnp.zeros((8, 1024)), jnp.zeros((8, 1)),
+                           jnp.zeros((8, 1)), tile=200)
 
 
 # ------------------------------- rwkv6 scan --------------------------------

@@ -29,6 +29,8 @@ V5E_HBM_BYTES = 16 * GiB
 #: The chip smoke's 512 MiB f32 messages and its one-chip Jacobi grid.
 MSG_ELEMS = 512 * MiB // 4
 JACOBI_ROWS, JACOBI_COLS = 8, 1 << 25
+#: The width of the benchmark's 2 GiB Jacobi cell (``jacobi.1chip.2G``).
+JACOBI_CELL_COLS = 1 << 26
 #: A diagonal message whose remote-DMA staging fits the 16 MiB of scoped
 #: VMEM a kernel gets by default (24 MiB still compiles, 32 MiB does not).
 DMA_BYTES = 16 * MiB
@@ -107,11 +109,43 @@ def test_extraction_program_512mib(mesh4, dst):
 
 def test_jacobi_kernel_at_smoke_size(one_chip):
     from repro.kernels.jacobi.kernel import jacobi_sweep_kernel
-    ext = jax.ShapeDtypeStruct((JACOBI_ROWS, JACOBI_COLS + 2), jnp.float32,
-                               sharding=one_chip)
+    u = jax.ShapeDtypeStruct((JACOBI_ROWS, JACOBI_COLS), jnp.float32,
+                             sharding=one_chip)
+    halo = jax.ShapeDtypeStruct((JACOBI_ROWS, 1), jnp.float32,
+                                sharding=one_chip)
     fn = jax.jit(functools.partial(jacobi_sweep_kernel, interpret=False))
-    compiled = fn.lower(ext).compile()
+    compiled = fn.lower(u, halo, halo).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+def test_jacobi_sweep_one_chip_at_cell_size(topo, monkeypatch):
+    """The whole one-chip sweep as the benchmark jits it, at the 2 GiB
+    cell's 8 x 2^26: the kernel at the tile the shape picks passes the
+    scoped-VMEM check, and reads the grid in place. No op but the kernel
+    makes anything as wide as the grid (no halo-extended block, no shifted
+    copy), and the temporaries are small."""
+    from repro.core.halo import jacobi_step
+    from repro.kernels.jacobi import ops as jacobi_ops
+    # The kernel's wrapper interprets on a CPU backend; compile it.
+    monkeypatch.setattr(jacobi_ops, "_is_cpu", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:1]), ("dev",))
+    fn = jax.jit(jax.shard_map(
+        lambda x: jacobi_step(x[0], "dev", multipath=True,
+                              use_kernel=True)[None],
+        mesh=mesh, in_specs=P("dev"), out_specs=P("dev"), check_vma=False))
+    u = jax.ShapeDtypeStruct((1, JACOBI_ROWS, JACOBI_CELL_COLS), jnp.float32,
+                             sharding=NamedSharding(mesh, P("dev")))
+    compiled = fn.lower(u).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    wide = re.findall(rf"%\S+ = \w+\[[\d,]*{JACOBI_CELL_COLS}\]\S* "
+                      rf"([\w-]+)\(", text)
+    assert set(wide) <= {"parameter", "bitcast", "custom-call"}, wide
+    assert wide.count("custom-call") == 1
+    grid_bytes = JACOBI_ROWS * JACOBI_CELL_COLS * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < grid_bytes // 16, mem
     _fits_one_chip(compiled)
 
 
