@@ -202,16 +202,39 @@ def top_ops(trace: Trace, devices: int, n: int = 10
     return [(k, v / devices / 1e9) for k, v in top]
 
 
+def window(trace: Trace, window_span: str) -> Event | None:
+    """The host span around the measured window."""
+    return next((e for e in trace.host_line(window_span)
+                 if e.name == window_span), None)
+
+
+def cut_short(trace: Trace, window_span: str, call_span: str,
+              margin: float = 0.1) -> list[Device]:
+    """The chips whose record stops well before the window's last call
+    (``call_span``) began: the profiler dropped the rest of their events,
+    and reading them would count the lost time as idle. Every chip runs
+    a part of each call's work, so a whole record ends after the last call
+    began. Device and host clocks differ by a few milliseconds, so
+    "well before" is by ``margin`` of the window's length."""
+    line = trace.host_line(window_span)
+    w = window(trace, window_span)
+    calls = [e for e in line if e.name == call_span]
+    if w is None or not calls:
+        return []
+    edge = calls[-1].start_ns - margin * (w.end_ns - w.start_ns)
+    return [d for d in trace.devices
+            if max((e.end_ns for e in d.ops), default=edge) < edge]
+
+
 def idle_gaps(trace: Trace, window_span: str, n: int = 10
               ) -> list[tuple[str, float]]:
-    """Device 0's idle time inside the window, grouped by what the driving
-    host thread was doing in the middle of each gap (its innermost span),
-    longest first, in seconds."""
+    """The first chip's idle time inside the window, grouped by what the
+    driving host thread was doing in the middle of each gap (its innermost
+    span), longest first, in seconds."""
     line = trace.host_line(window_span)
-    windows = [e for e in line if e.name == window_span]
-    if not trace.devices or not windows:
+    w = window(trace, window_span)
+    if not trace.devices or w is None:
         return []
-    w = windows[0]
     busy = union([(w.start_ns, w.start_ns), (w.end_ns, w.end_ns)]
                  + union(trace.devices[0].ops))
     total: dict[str, float] = defaultdict(float)

@@ -1,12 +1,15 @@
 """Point-to-point sends through ``CommSession.send``, as OSU's ``osu_bw``
 and ``osu_latency`` drive a pair of devices.
 
-One caller, one send in flight: each send starts from a message committed
-to ``src``, where its caller holds it, and ends when the received array is
-ready. Messages cycle through a pool of distinct seeded messages, so a send
-that hands back an earlier answer is caught. A sample of the answers, drawn
-from the seed, is compared after the window with a plain single-pair
-``lax.ppermute`` of the same message, bit for bit on ``dst``.
+One caller, one send in flight (a window of 1, where ``osu_bw`` keeps 64):
+each send starts from a message committed to ``src``, where its caller
+holds it, and ends when the received array is ready on ``dst``. Messages
+cycle through a pool of distinct seeded messages. A sample of the answers,
+drawn from the seed, is compared after the window with a plain single-pair
+``lax.ppermute`` of the same message, bit for bit on ``dst``. Every answer
+must also be a buffer of its own: the answers of the last pool's worth of
+sends are held, and one that shares a buffer with any of them is an
+earlier answer handed back, whose bits the comparison would pass.
 
 Traffic parameters (``params`` of the workload file): ``src``, ``dst``,
 ``nbytes`` (a whole number of elements of the configuration's dtype),
@@ -16,12 +19,17 @@ Traffic parameters (``params`` of the workload file): ``src``, ``dst``,
 
 from __future__ import annotations
 
+from collections import Counter, deque
+
 import numpy as np
 
 import harness
 import reference
 
-END_TO_END = {"bw_GBps": "GB/s", "lat_p95_us": "us"}
+#: Both are the window over the sends completed in it. A cell reports
+#: ``send_ms`` where the device paces its sends, under a bound near its
+#: own spread, and ``step_ms`` where the host does.
+END_TO_END = {"send_ms": "ms", "step_ms": "ms"}
 
 
 class Run:
@@ -59,6 +67,10 @@ class Run:
         del stacked
         self.samples = harness.Reservoir(int(p["sample"]), s.seed)
         self.n = 0
+        self.stale = 0
+        #: (answer, its buffers) of the last ``count`` sends, and how many
+        #: of them hold each buffer.
+        self._recent, self._held = deque(), Counter()
         for i in range(int(p["warmup"])):
             jax.block_until_ready(self._op(i))
 
@@ -83,15 +95,23 @@ class Run:
         return self._op(self.n)
 
     def done(self, out) -> None:
+        bufs = {s.data.unsafe_buffer_pointer()
+                for s in out.addressable_shards}
+        self.stale += any(self._held[b] for b in bufs)
+        if len(self._recent) == self.count:
+            self._held.subtract(self._recent.popleft()[1])
+        self._recent.append((out, bufs))
+        self._held.update(bufs)
         self.samples.offer(out)
         self.n += 1
 
     def end_to_end(self, window_s: float, lat_ns: list) -> dict[str, float]:
-        """Bytes received over the whole window, and the 95th percentile of
-        all sends in it, each from the call until the received array is
-        ready."""
-        return {"bw_GBps": len(lat_ns) * self.nbytes / window_s / 1e9,
-                "lat_p95_us": harness.percentile(lat_ns, 95) / 1e3}
+        """The window over the sends completed in it, each waited on until
+        the received array is ready on ``dst``: the one-way time of a send
+        with one in flight. It is not ``osu_bw``'s bandwidth, which keeps
+        64 sends in flight."""
+        ms = window_s / len(lat_ns) * 1e3
+        return {"send_ms": ms, "step_ms": ms}
 
     def layer_inputs(self) -> dict:
         tel = self.session.telemetry
@@ -100,7 +120,7 @@ class Run:
 
     def release(self) -> None:
         """Free the program's state; the sampled answers stay."""
-        for attr in ("messages", "_stacked", "session"):
+        for attr in ("messages", "_stacked", "session", "_recent", "_held"):
             self.__dict__.pop(attr, None)
 
     def check(self) -> tuple[list[harness.Check], int]:
@@ -118,7 +138,9 @@ class Run:
             got = reference.on_device(out, dst_dev)
             bad = reference.mismatched_elements(got, wants[i % self.count])
             worst, failed = max(worst, bad), failed + (bad > 0)
-        return [harness.Check("mismatched_elems", float(worst), 0.0)], failed
+        return [harness.Check("mismatched_elems", float(worst), 0.0),
+                harness.Check("stale_answers", float(self.stale), 0.0)
+                ], failed + self.stale
 
 
 def setup(s) -> Run:

@@ -4,8 +4,8 @@ A cell is ``workloads/<cell>.json``. It names its configuration
 (``configs/<config>.json``), its traffic driver (``drivers/<kind>.py``) and
 the end-to-end metrics it reports. Every per-layer metric is a reader of its
 own, ``metrics/<metric>.py``, that declares the one end-to-end metric it
-moves; a traced run reads each metric whose end-to-end metric the cell
-reports. Adding a cell, a driver or a metric is adding a file.
+moves; a traced run reads the metrics that ``BENCHMARK.json`` lists for the
+cell. Adding a cell, a driver or a metric is adding a file and entries.
 
 This module imports no JAX, so that the tests of what it finds run without
 a device.
@@ -24,6 +24,7 @@ from types import ModuleType
 from typing import Any, Callable
 
 BENCH = Path(__file__).resolve().parent
+SPEC = BENCH.parent / "BENCHMARK.json"
 
 #: The end-to-end metric that every cell reports.
 SETUP = "setup_s"
@@ -136,10 +137,24 @@ def load_metrics(root: Path = BENCH) -> dict[str, Metric]:
     return out
 
 
-def metrics_for(cell: Cell, metrics: dict[str, Metric]) -> list[Metric]:
-    """The per-layer metrics a traced run of ``cell`` reads: those that
-    move an end-to-end metric the cell reports."""
-    return [m for m in metrics.values() if m.moves in cell.end_to_end]
+def load_spec(path: Path = SPEC) -> dict:
+    """``BENCHMARK.json``: the cells and the metrics each reports."""
+    return _load_json(path, "benchmark spec")
+
+
+def metrics_for(cell: Cell, metrics: dict[str, Metric],
+                spec: dict) -> list[Metric]:
+    """The per-layer metrics a traced run of ``cell`` reads: those whose
+    entry in ``spec`` lists the cell under ``workloads``."""
+    out = []
+    for entry in spec["per_layer"]:
+        if cell.name in entry["workloads"]:
+            try:
+                out.append(metrics[entry["name"]])
+            except KeyError:
+                raise BenchError(f"no reader for per-layer metric "
+                                 f"{entry['name']!r}") from None
+    return out
 
 
 def load_peaks(device_kind: str, root: Path = BENCH) -> dict:
@@ -151,15 +166,6 @@ def load_peaks(device_kind: str, root: Path = BENCH) -> dict:
     except KeyError:
         raise BenchError(f"no peaks for device kind {device_kind!r} in "
                          f"peaks.json") from None
-
-
-def percentile(values, q: float) -> float:
-    """The nearest-rank ``q``-th percentile of ``values``: the smallest
-    value that at least ``q`` per cent of them do not exceed."""
-    if not values:
-        raise BenchError("percentile of no values")
-    ordered = sorted(values)
-    return ordered[max(math.ceil(q / 100 * len(ordered)), 1) - 1]
 
 
 class Reservoir:

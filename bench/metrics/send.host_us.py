@@ -8,7 +8,7 @@ UNIT = "us"
 BETTER = "lower"
 LAYER = "session fast path"
 SOURCE = "program_span"
-MOVES = "lat_p95_us"
+MOVES = "send_ms"
 
 
 def read(x):

@@ -9,9 +9,9 @@ UNIT = "ms"
 BETTER = "lower"
 LAYER = "staging and result placement"
 SOURCE = "device_trace"
-MOVES = "bw_GBps"
+MOVES = "send_ms"
 
-#: The transfer program, as ``bw.program_ms`` finds it.
+#: The transfer program, as ``send.program_ms`` finds it.
 MODULE = re.compile(r"local_body")
 
 

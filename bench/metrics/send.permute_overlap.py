@@ -1,8 +1,9 @@
 """How many of the transfer program's collective-permutes are in flight at
-once on device 0: the sum of their durations over the length of their
-union. 1.0 means the paths ran one after another. A permute runs from its
-``collective-permute-start`` to the end of its ``collective-permute-done``
-(or is one synchronous ``collective-permute``)."""
+once, on the chip that spends most time in the transfer program: the sum
+of their durations over the length of their union. 1.0 means the paths ran
+one after another. A permute runs from its ``collective-permute-start`` to
+the end of its ``collective-permute-done`` (or is one synchronous
+``collective-permute``)."""
 
 import re
 
@@ -12,9 +13,9 @@ UNIT = "x"
 BETTER = "higher"
 LAYER = "device program"
 SOURCE = "device_trace"
-MOVES = "bw_GBps"
+MOVES = "send_ms"
 
-#: The transfer program, as ``bw.program_ms`` finds it.
+#: The transfer program, as ``send.program_ms`` finds it.
 MODULE = re.compile(r"local_body")
 
 
@@ -35,9 +36,11 @@ def permute_intervals(ops) -> list[tuple[float, float]]:
 
 
 def read(x):
-    if not x.trace.devices:
+    chips = x.trace.devices[:x.chips]
+    if not chips:
         return None
-    spans = permute_intervals(x.trace.devices[0].ops_in(MODULE))
+    busiest = max(chips, key=lambda d: d.module_ns(MODULE))
+    spans = permute_intervals(busiest.ops_in(MODULE))
     if not spans:
         return None
     return sum(e - s for s, e in spans) / devtrace.length(

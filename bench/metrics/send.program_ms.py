@@ -8,7 +8,7 @@ UNIT = "ms"
 BETTER = "lower"
 LAYER = "device program"
 SOURCE = "device_trace"
-MOVES = "bw_GBps"
+MOVES = "send_ms"
 
 #: The transfer program: the jitted ``shard_map`` of the engine's graph body.
 MODULE = re.compile(r"local_body")

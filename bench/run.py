@@ -42,8 +42,8 @@ for _p in (str(ROOT / "src"), str(BENCH)):
 
 import harness  # noqa: E402
 
-#: Host span around the measured window in a traced run.
-WINDOW_SPAN = "bench.window"
+#: Host spans around the measured window and each call in a traced run.
+WINDOW_SPAN, CALL_SPAN = "bench.window", "bench.call"
 #: JAX records this once for every program it compiles or loads from the
 #: persistent cache.
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -64,7 +64,11 @@ class Setup:
 class LayerInputs:
     """What a per-layer metric reads."""
 
+    #: The run's profile, without the chips whose record stops early
+    #: (``devtrace.cut_short``).
     trace: object
+    #: The ``*.xplane.pb`` that ``trace`` was read from.
+    trace_path: Path | None
     ops: int
     window_s: float
     chips: int
@@ -152,7 +156,7 @@ def measure(run, seconds: float, traced: bool = False) -> tuple[list, float]:
         end = t0 + int(seconds * 1e9)
         while True:
             t = clock()
-            with span("bench.call"):
+            with span(CALL_SPAN):
                 out = run.call()
             with span("bench.wait"):
                 out.block_until_ready()
@@ -179,8 +183,8 @@ def run_cell(cell: harness.Cell, devices, *, seed: int, seconds: float,
     driver = harness.load_driver(cell.driver)
     if peaks is None:
         peaks = harness.load_peaks(devices[0].device_kind)
-    readers = (harness.metrics_for(cell, harness.load_metrics())
-               if trace else [])
+    readers = (harness.metrics_for(cell, harness.load_metrics(),
+                                   harness.load_spec()) if trace else [])
     counter = CompileCounter()
     run = driver.setup(Setup(cell=cell, devices=list(devices), seed=seed,
                              control=control))
@@ -216,15 +220,24 @@ def run_cell(cell: harness.Cell, devices, *, seed: int, seconds: float,
         breakdown = None
         if trace:
             import devtrace
-            tr = devtrace.load(tracedir)
+            trace_path = devtrace.find_xplane(tracedir)
+            tr = devtrace.load(trace_path)
+            short = {d.id for d in devtrace.cut_short(tr, WINDOW_SPAN,
+                                                        CALL_SPAN)}
+            if short:
+                print(f"bench: the profile of chip(s) {sorted(short)} stops "
+                      "before the window's end; they are left out of "
+                      "busy_s and the per-layer metrics", file=sys.stderr)
+                tr = devtrace.Trace([d for d in tr.devices
+                                     if d.id not in short], tr.host)
             used = tr.devices[:len(devices)]
             device["busy_s"] = sum(d.busy_ns() for d in used) / len(
                 used) / 1e9 if used else 0.0
             device["window_s"] = window_s
-            inputs = LayerInputs(trace=tr, ops=n, window_s=window_s,
-                                 chips=len(devices), peaks=peaks,
-                                 params=cell.params, config=cell.config,
-                                 extra=extra)
+            inputs = LayerInputs(trace=tr, trace_path=trace_path, ops=n,
+                                 window_s=window_s, chips=len(devices),
+                                 peaks=peaks, params=cell.params,
+                                 config=cell.config, extra=extra)
             metrics = {}
             for m in readers:
                 v = m.read(inputs)

@@ -25,7 +25,9 @@ def run_small(name, seed=2**33 + 17, **kw):
         cell, params={**cell.params, **SMALL[cell.driver]})
     line = run.run_cell(cell, jax.devices()[:cell.chips], seed=seed,
                         seconds=0.3, trace=False, peaks=PEAKS, **kw)
-    return json.loads(line)
+    out = json.loads(line)
+    assert set(out["metrics"]) == {*cell.end_to_end, harness.SETUP}
+    return out
 
 
 @pytest.mark.parametrize("name", ["p2p.diag.512M", "p2p.nbr.32B",
@@ -53,6 +55,18 @@ def _stale(send):
     return faulty
 
 
+def _reused(send):
+    """Hands back, for a message it has sent before, the answer it gave
+    then: its bits are right, but it is no answer of this send."""
+    given = {}
+
+    def faulty(self, x, src, dst, **kw):
+        if id(x) not in given:
+            given[id(x)] = send(self, x, src, dst, **kw)
+        return given[id(x)]
+    return faulty
+
+
 def _half(send):
     def faulty(self, x, src, dst, **kw):
         out = send(self, x, src, dst, **kw)
@@ -74,7 +88,8 @@ def _altered(send):
     return faulty
 
 
-@pytest.mark.parametrize("fault", [_stale, _half, _no_exchange, _altered])
+@pytest.mark.parametrize("fault", [_stale, _reused, _half, _no_exchange,
+                                   _altered])
 def test_send_fault_is_not_correct(monkeypatch, fault):
     from repro.comm.session import CommSession
     monkeypatch.setattr(CommSession, "send", fault(CommSession.send))

@@ -21,19 +21,24 @@ def test_dropped_files_are_found_without_an_edit(tmp_path):
     w = json.loads((root / "workloads" / "p2p.nbr.32B.json").read_text())
     w.update(traffic="nbr_4K", params={**w["params"], "nbytes": 4096})
     (root / "workloads" / "p2p.nbr.4K.json").write_text(json.dumps(w))
-    (root / "metrics" / "lat.extra_us.py").write_text(
+    (root / "metrics" / "send.extra_us.py").write_text(
         'UNIT = "us"\nBETTER = "lower"\nLAYER = "session fast path"\n'
-        'SOURCE = "program_span"\nMOVES = "lat_p95_us"\n'
+        'SOURCE = "program_span"\nMOVES = "step_ms"\n'
         'def read(x):\n    return None\n')
+    # Listing the cell and its metrics is adding entries to BENCHMARK.json.
+    spec = {**SPEC, "per_layer": SPEC["per_layer"] + [
+        {"name": n, "moves": "step_ms", "workloads": ["p2p.nbr.4K"]}
+        for n in ("send.host_us", "send.device_us", "send.extra_us")]}
 
     assert "p2p.nbr.4K" in harness.cell_names(root)
     cell = harness.load_cell("p2p.nbr.4K", root)
     assert (cell.config_name, cell.chips, cell.params["nbytes"]) == (
         "omb_p2p", 4, 4096)
     assert hasattr(harness.load_driver(cell.driver, root), "setup")
-    names = {m.name for m in harness.metrics_for(
-        cell, harness.load_metrics(root))}
-    assert names == {"lat.host_us", "lat.device_us", "lat.extra_us"}
+    metrics = harness.load_metrics(root)
+    names = {m.name for m in harness.metrics_for(cell, metrics, spec)}
+    assert names == {"send.host_us", "send.device_us", "send.extra_us"}
+    assert not harness.metrics_for(cell, metrics, SPEC)
 
 
 def test_missing_or_malformed_parts_are_errors(tmp_path):
@@ -90,9 +95,11 @@ def test_cells_match_their_files():
 
 def test_every_per_layer_metric_is_read_where_its_moves_is_reported():
     metrics = harness.load_metrics()
-    assert {m["name"] for m in SPEC["per_layer"]} <= set(metrics)
+    end_to_end = {e["name"] for e in SPEC["end_to_end"]}
     for m in SPEC["per_layer"]:
+        assert (harness.BENCH / "metrics" / f"{m['name']}.py").is_file()
         reader = metrics[m["name"]]
+        assert reader.moves in end_to_end
         assert (reader.unit, reader.better, reader.layer, reader.source,
                 reader.moves) == (m["unit"], m["better"], m["layer"],
                                   m["source"], m["moves"])
@@ -100,7 +107,7 @@ def test_every_per_layer_metric_is_read_where_its_moves_is_reported():
             assert m["moves"] in harness.load_cell(name).end_to_end
     for w in SPEC["workloads"]:
         read = {m.name for m in harness.metrics_for(
-            harness.load_cell(w["name"]), metrics)}
+            harness.load_cell(w["name"]), metrics, SPEC)}
         listed = {m["name"] for m in SPEC["per_layer"]
                   if w["name"] in m["workloads"]}
         assert read == listed and read
@@ -132,12 +139,3 @@ def test_result_line_puts_the_checks_last():
     assert out["correct"] is False
     assert list(out)[-1] == "checks"
     assert out["checks"]["gap"] == {"value": "inf", "limit": 0.0}
-
-
-def test_percentile_is_nearest_rank():
-    assert harness.percentile([5, 1, 4, 2, 3], 95) == 5
-    assert harness.percentile(list(range(1, 101)), 95) == 95
-    assert harness.percentile(list(range(1, 201)), 95) == 190
-    assert harness.percentile([7], 95) == 7
-    with pytest.raises(harness.BenchError):
-        harness.percentile([], 95)

@@ -22,20 +22,15 @@ SCOPED = {"jacobi.1chip.2G": DATA / "jacobi.1chip.2G.scoped.xplane.pb",
 STEP = ("step.dispatch_us", "step.queue_us", "step.notify_us")
 
 
-def inputs(cell_name, path, tmp_path, monkeypatch, ops=None):
-    """What a traced run of ``cell_name`` hands its readers, with the trace
-    where ``run.py`` leaves it while they read."""
-    where = tmp_path / "bench-trace-x" / "plugins" / "profile" / "t"
-    where.mkdir(parents=True)
-    shutil.copy(path, where / "host.xplane.pb")
-    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+def inputs(cell_name, path, ops=None):
+    """What a traced run of ``cell_name`` hands its readers."""
     trace = devtrace.load(path)
     cell = harness.load_cell(cell_name)
     return LayerInputs(
-        trace=trace, ops=ops or len(trace.devices[0].modules), window_s=0.0,
-        chips=1, peaks=harness.load_peaks("TPU v5 lite"),
-        params=cell.params, config=cell.config,
-        extra={"rows": 8, "cols": cell.params["cols"]})
+        trace=trace, trace_path=path,
+        ops=ops or len(trace.devices[0].modules), window_s=0.0, chips=1,
+        peaks=harness.load_peaks("TPU v5 lite"), params=cell.params,
+        config=cell.config, extra={"rows": 8, "cols": cell.params["cols"]})
 
 
 def read_all(x):
@@ -57,9 +52,8 @@ def test_decoder_reads_each_ops_metadata():
     assert xspace.metadata_stats(UNSCOPED, "/device:TPU:7") == {}
 
 
-def test_the_trace_without_scopes_has_no_glue(tmp_path, monkeypatch):
-    got = read_all(inputs("jacobi.1chip.2G", UNSCOPED, tmp_path,
-                          monkeypatch))
+def test_the_trace_without_scopes_has_no_glue():
+    got = read_all(inputs("jacobi.1chip.2G", UNSCOPED))
     assert got["jacobi.glue_ms"] is None
     assert 36.5 < got["jacobi.kernel_ms"] < 37.5
     # The host hand-off needs no scope: 207.6, 547.8 and 76.0 us.
@@ -69,8 +63,15 @@ def test_the_trace_without_scopes_has_no_glue(tmp_path, monkeypatch):
 
 
 def test_a_trace_of_another_run_is_not_read(tmp_path, monkeypatch):
-    x = inputs("jacobi.1chip.2G", UNSCOPED, tmp_path, monkeypatch)
-    x.trace.devices[0].modules.pop()
+    # Another run's trace, without scopes, is the newest under the
+    # temporary directory: the readers read the run's own all the same.
+    other = tmp_path / "bench-trace-other" / "plugins" / "profile" / "t"
+    other.mkdir(parents=True)
+    shutil.copy(UNSCOPED, other / "host.xplane.pb")
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    x = inputs("jacobi.1chip.2G", SCOPED["jacobi.1chip.2G"])
+    assert 12.5 < read_all(x)["jacobi.glue_ms"] < 13.5
+    x.trace_path = None
     assert all(v is None for k, v in read_all(x).items()
                if k != "jacobi.kernel_ms")
 
@@ -109,8 +110,8 @@ def test_a_program_without_one_callback_gives_no_sweeps(callbacks):
 
 
 @pytest.mark.parametrize("cell", sorted(SCOPED))
-def test_glue_and_kernel_cover_the_busy_time(cell, tmp_path, monkeypatch):
-    x = inputs(cell, SCOPED[cell], tmp_path, monkeypatch)
+def test_glue_and_kernel_cover_the_busy_time(cell):
+    x = inputs(cell, SCOPED[cell])
     got = read_all(x)
     busy_ms = x.trace.devices[0].busy_ns() / x.ops / 1e6
     unscoped = [e for e in x.trace.devices[0].ops
@@ -129,8 +130,8 @@ def test_glue_and_kernel_cover_the_busy_time(cell, tmp_path, monkeypatch):
 
 
 @pytest.mark.parametrize("cell", sorted(SCOPED))
-def test_the_hand_off_adds_up_to_the_sweep(cell, tmp_path, monkeypatch):
-    x = inputs(cell, SCOPED[cell], tmp_path, monkeypatch)
+def test_the_hand_off_adds_up_to_the_sweep(cell):
+    x = inputs(cell, SCOPED[cell])
     got = read_all(x)
     run = xspace.load(x)
     per_sweep_us = statistics.median(s.waited - s.call

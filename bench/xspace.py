@@ -8,18 +8,14 @@ module decodes those from the ``XSpace`` wire format (tsl's ``xplane.proto``:
 an ``XSpace`` holds ``XPlane``s, each with ``event_metadata`` and
 ``stat_metadata`` maps).
 
-``run.py`` writes a traced run's trace to a fresh ``bench-trace-*``
-directory under the temporary directory, and removes it after the
-per-layer readers ran; :func:`load` reads the newest such trace, and only
-if it is the one the run reduced (the same number of program executions
-on device 0).
+:func:`load` reads the trace that the run itself wrote, whose path
+``run.py`` hands the per-layer readers (``LayerInputs.trace_path``).
 """
 
 from __future__ import annotations
 
 import functools
 import struct
-import tempfile
 from dataclasses import dataclass
 from pathlib import Path
 
@@ -145,31 +141,16 @@ class Run:
     sweeps: list[Sweep] | None
 
 
-def trace_file() -> Path | None:
-    """The newest trace that a traced run of ``run.py`` wrote under the
-    temporary directory."""
-    dirs = sorted(Path(tempfile.gettempdir()).glob("bench-trace-*"),
-                  key=lambda p: p.stat().st_mtime)
-    for d in reversed(dirs):
-        try:
-            return devtrace.find_xplane(d)
-        except FileNotFoundError:
-            continue
-    return None
-
-
 def load(x) -> Run | None:
-    """What the run's trace holds beyond ``x.trace``, or None when the
-    newest trace is not the one ``x.trace`` was read from."""
-    path = trace_file()
-    if path is None or not x.trace.devices:
+    """What the run's own trace holds beyond ``x.trace``; None where the
+    run gives no trace file."""
+    if x.trace_path is None or not x.trace.devices:
         return None
-    run, modules = _read(str(path), path.stat().st_mtime_ns)
-    return run if modules == len(x.trace.devices[0].modules) else None
+    return _read(str(x.trace_path))
 
 
 @functools.lru_cache(maxsize=1)
-def _read(path: str, _mtime_ns: int) -> tuple[Run, int]:
+def _read(path: str) -> Run:
     from jax.profiler import ProfileData
 
     tf_op = {k: v["tf_op"] for k, v in metadata_stats(path, DEVICE0).items()
@@ -198,9 +179,7 @@ def _read(path: str, _mtime_ns: int) -> tuple[Run, int]:
                     calls.append(e)
                 elif e.name == WAIT:
                     waits.append(e)
-    n_modules = sum(len(v) for v in modules.values())
-    return Run(tf_op, _sweeps(calls, waits, enqueues, modules,
-                              callbacks)), n_modules
+    return Run(tf_op, _sweeps(calls, waits, enqueues, modules, callbacks))
 
 
 def _sweeps(calls, waits, enqueues, modules, callbacks) -> list[Sweep] | None:
